@@ -22,6 +22,12 @@ import json
 import os
 import time
 
+if __name__ == "__main__":
+    # the w-scaling sweep shards over the devices present; on the CPU
+    # that is 8 host devices, forced before JAX starts (no effect on TPU)
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -73,9 +79,10 @@ def bench_gossip(f: int = 4096, out_path: str = "BENCH_gossip.json"):
     (fp32 / bf16 / int8 + per-row scale) — the sparse-topology economy and
     the wire-format economy compose (~4× on top of nnz/W²).
 
-    All kernels run single-tile (block_f=f): interpret mode pays a large
+    The kernels' parameter block is capped at f (block_f=f), so they run
+    single-tile wherever the tile fits VMEM: interpret mode pays a large
     fixed cost per grid step that would otherwise swamp the compute
-    difference being measured (on TPU the streaming grid is free)."""
+    difference being measured."""
     import functools
 
     from repro.core.gossip import quantize_rows_int8, sparse_weights
@@ -856,102 +863,81 @@ def bench_w_scaling():
     the dry-run cost column prints). Numerics: every shard count must
     agree with the single-shard mix at the same W.
 
-    The whole sweep runs in ONE forced-8-device subprocess (this process
-    keeps the default single CPU device, same discipline as
-    tests/test_distributed.py); wall times are best-of-3 on a shared CPU
-    core, so rows are regression trajectories, not device latencies."""
-    import subprocess
-    import sys
-    import textwrap
+    The sweep runs in this process on the devices present, over the
+    shard counts they allow (run as a script on the CPU, this module
+    forces 8 host devices before JAX starts); wall times are best-of-3,
+    regression trajectories on the platform that ran them."""
+    from repro.config import DeFTAConfig, TrainConfig
+    from repro.core.defta import run_defta
+    from repro.core.gossip import mix_pytree_sharded, worker_shard_plan
+    from repro.core.tasks import mlp_task
+    from repro.core.topology import make_topology
+    from repro.data.synthetic import federated_dataset
+    from repro.launch.roofline import sharded_ring_bytes
+    from repro.sharding import WorkerShards, worker_mesh
+    from repro.telemetry import RunLedger
 
-    code = textwrap.dedent("""
-        import json, time
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.config import DeFTAConfig, TrainConfig
-        from repro.core.defta import run_defta
-        from repro.core.gossip import mix_pytree_sharded, worker_shard_plan
-        from repro.core.tasks import mlp_task
-        from repro.core.topology import make_topology
-        from repro.data.synthetic import federated_dataset
-        from repro.launch.roofline import sharded_ring_bytes
-        from repro.sharding import WorkerShards, worker_mesh
-        from repro.telemetry import RunLedger
+    shard_counts = [s for s in (1, 4, 8) if s <= len(jax.devices())]
+    F = 256
+    rows = []
+    for w in (500, 2000, 10000):
+        adj = make_topology("random_kout", w, 4, seed=0)
+        P = (adj | np.eye(w, dtype=bool)).astype(np.float32)
+        P = jnp.asarray(P / P.sum(1, keepdims=True))
+        stack = {"p": jax.random.normal(jax.random.PRNGKey(w), (w, F))}
+        base = None                     # the shards=1 mix at this W
+        for shards in shard_counts:
+            shard = WorkerShards(mesh=worker_mesh(shards))
+            plan = worker_shard_plan(adj, shards)
+            roof = sharded_ring_bytes(F, adj, shards, None, rows=1)
 
-        F = 256
-        rows = []
-        for w in (500, 2000, 10000):
-            adj = make_topology("random_kout", w, 4, seed=0)
-            P = (adj | np.eye(w, dtype=bool)).astype(np.float32)
-            P = jnp.asarray(P / P.sum(1, keepdims=True))
-            stack = {"p": jax.random.normal(jax.random.PRNGKey(w), (w, F))}
-            base = None                     # the shards=1 mix at this W
-            for shards in (1, 4, 8):
-                shard = WorkerShards(mesh=worker_mesh(shards))
-                plan = worker_shard_plan(adj, shards)
-                roof = sharded_ring_bytes(F, adj, shards, None, rows=1)
+            def mix(P_, s_, _mesh=shard.mesh, _ax=shard.axis):
+                return mix_pytree_sharded(P_, s_, _mesh, axis=_ax,
+                                          adjacency=adj)
+            fn = jax.jit(mix)
+            out = jax.block_until_ready(fn(P, stack))
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.time()
+                jax.block_until_ready(fn(P, stack))
+                best = min(best, time.time() - t0)
+            # pull to host: shard-count runs live on different device
+            # sets, jnp ops across them are rejected
+            out = np.asarray(jax.device_get(out["p"]))
+            if base is None:
+                base = out
+            err = float(np.max(np.abs(out - base)))
+            rows.append(dict(
+                W=w, shards=shards, mix_ms=best * 1e3,
+                ring_bytes=float(plan.ring_bytes(F)),
+                ring_bytes_ok=bool(
+                    plan.ring_bytes(F) == roof["ring_bytes"]),
+                bytes_per_boundary=roof["bytes_per_boundary"],
+                used_pairs=roof["used_pairs"],
+                intra_edges=roof["intra_edges"],
+                cross_edges=roof["cross_edges"],
+                err_vs_single_shard=err))
+            assert err < 5e-5, (w, shards, err)
 
-                def mix(P_, s_, _mesh=shard.mesh, _ax=shard.axis):
-                    return mix_pytree_sharded(P_, s_, _mesh, axis=_ax,
-                                              adjacency=adj)
-                fn = jax.jit(mix)
-                out = jax.block_until_ready(fn(P, stack))
-                best = float("inf")
-                for _ in range(3):
-                    t0 = time.time()
-                    jax.block_until_ready(fn(P, stack))
-                    best = min(best, time.time() - t0)
-                # pull to host: shard-count runs live on different device
-                # sets, jnp ops across them are rejected
-                out = np.asarray(jax.device_get(out["p"]))
-                if base is None:
-                    base = out
-                err = float(np.max(np.abs(out - base)))
-                rows.append(dict(
-                    W=w, shards=shards, mix_ms=best * 1e3,
-                    ring_bytes=float(plan.ring_bytes(F)),
-                    ring_bytes_ok=bool(
-                        plan.ring_bytes(F) == roof["ring_bytes"]),
-                    bytes_per_boundary=roof["bytes_per_boundary"],
-                    used_pairs=roof["used_pairs"],
-                    intra_edges=roof["intra_edges"],
-                    cross_edges=roof["cross_edges"],
-                    err_vs_single_shard=err))
-                assert err < 5e-5, (w, shards, err)
-
-        # engine dispatch parity per shard count: a 2-epoch W=500 run with
-        # eval_every=2 is ONE dispatch, sharded or not
-        w = 500
-        cfg = DeFTAConfig(num_workers=w, avg_peers=4, num_sampled=2,
-                          local_epochs=1)
-        train = TrainConfig(learning_rate=0.05, batch_size=16)
-        data = federated_dataset("vector", w, np.random.default_rng(0),
-                                 n_per_worker=16, alpha=0.5)
-        task = mlp_task(32, 10)
-        engine = []
-        for shards in (1, 4, 8):
-            led = RunLedger()
-            st, _, _, _ = run_defta(jax.random.PRNGKey(0), task, cfg,
-                                    train, data, epochs=2, eval_every=2,
-                                    ledger=led,
-                                    shards=None if shards == 1 else shards)
-            engine.append(dict(W=w, shards=shards, epochs=2,
-                               dispatches=led.dispatches,
-                               dispatch_budget=1,
-                               wall_s=led.wall_s,
-                               round_s=led.wall_s / 2))
-        print(json.dumps(dict(feature_dim=F, avg_peers=4, rows=rows,
-                              engine=engine)))
-    """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                       "src")
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (src, env.get("PYTHONPATH", "")) if p)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=1200, env=env)
-    assert r.returncode == 0, r.stdout + "\n" + r.stderr
-    payload = json.loads(r.stdout.strip().splitlines()[-1])
+    # engine dispatch parity per shard count: a 2-epoch W=500 run with
+    # eval_every=2 is ONE dispatch, sharded or not
+    w = 500
+    cfg = DeFTAConfig(num_workers=w, avg_peers=4, num_sampled=2,
+                      local_epochs=1)
+    train = TrainConfig(learning_rate=0.05, batch_size=16)
+    data = federated_dataset("vector", w, np.random.default_rng(0),
+                             n_per_worker=16, alpha=0.5)
+    task = mlp_task(32, 10)
+    engine = []
+    for shards in shard_counts:
+        led = RunLedger()
+        run_defta(jax.random.PRNGKey(0), task, cfg, train, data, epochs=2,
+                  eval_every=2, ledger=led,
+                  shards=None if shards == 1 else shards)
+        engine.append(dict(W=w, shards=shards, epochs=2,
+                           dispatches=led.dispatches, dispatch_budget=1,
+                           wall_s=led.wall_s, round_s=led.wall_s / 2))
+    payload = dict(feature_dim=F, avg_peers=4, rows=rows, engine=engine)
     for row in payload["rows"]:
         print(f"w_scaling W={row['W']:6d} shards={row['shards']} "
               f"mix={row['mix_ms']:8.1f}ms ring="
@@ -1002,5 +988,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.cache import enable_compile_cache
+    enable_compile_cache()
     run()
     bench_gossip()

@@ -8,6 +8,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.config import DeFTAConfig, TrainConfig
 from repro.core.defta import evaluate, run_defta
 from repro.core.fedavg import evaluate_server, run_fedavg
@@ -50,4 +51,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

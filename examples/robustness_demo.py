@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.config import DeFTAConfig, TrainConfig
 from repro.core import dts
 from repro.core.defta import evaluate, run_defta
@@ -114,4 +115,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.cache import enable_compile_cache
 from repro.config import reduced
 from repro.configs import get_config
 from repro.models import model as mm
@@ -64,4 +65,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.config import ModelConfig
 from repro.core.aggregation import mixing_matrix
 from repro.core.gossip import mix_pytree
@@ -90,4 +91,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

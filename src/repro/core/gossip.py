@@ -55,6 +55,12 @@ import numpy as np
 
 SPARSE_DENSITY_THRESHOLD = 0.25
 
+# Every gossip contraction runs at full f32 precision. TPU's default
+# rounds f32 matmul operands to bf16, which perturbs each row-stochastic
+# mixing row by ~2^-9 and lets the transports (kernels, einsum, ring
+# matmuls) drift apart; the CPU computes f32 either way.
+MIX_PRECISION = jax.lax.Precision.HIGHEST
+
 # bytes per parameter on the wire, by format (int8 adds 4 B/row of scales,
 # accounted in launch.roofline.gossip_wire_bytes)
 WIRE_BYTES = {None: 4, "fp32": 4, "bf16": 2, "int8": 1}
@@ -296,7 +302,8 @@ def mix_pytree(P, stacked, backend: str = "einsum", *, adjacency=None,
         if backend == "einsum":
             Pw = Pf * scale[None, :] if scale is not None else Pf
             return jnp.einsum("ij,jf->if", Pw,
-                              payload.astype(jnp.float32))
+                              payload.astype(jnp.float32),
+                              precision=MIX_PRECISION)
         if backend == "pallas":
             from repro.kernels.ops import gossip_mix
             Pw = Pf * scale[None, :] if scale is not None else Pf
@@ -403,7 +410,7 @@ def _mix_pytree_secagg(P, stacked, adjacency, *, wire, residual,
         else:
             weights = val
         out = jnp.einsum("wk,wkf->wf", weights,
-                         rec.astype(jnp.float32))
+                         rec.astype(jnp.float32), precision=MIX_PRECISION)
         outs.append(out.reshape(x.shape).astype(x.dtype))
         new_rs.append(nr)
     mixed = jax.tree.unflatten(treedef, outs)
@@ -456,8 +463,6 @@ def mix_pytree_ppermute(P, stacked, mesh, axis: str = "pod",
     NaN would otherwise poison.
     """
     from jax.sharding import PartitionSpec as Ps
-
-    from repro.compat import shard_map
 
     w = P.shape[0]
     wire = normalize_wire(wire)
@@ -569,7 +574,7 @@ def mix_pytree_ppermute(P, stacked, mesh, axis: str = "pod",
     if has_scale:
         in_specs = in_specs + specs
         operands += scales
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=specs, check_vma=False)
@@ -719,7 +724,6 @@ def mix_pytree_sharded(P, stacked, mesh, axis: str = "worker",
     """
     from jax.sharding import PartitionSpec as Ps
 
-    from repro.compat import shard_map
     from repro.kernels.ops import gossip_mix_quant, gossip_mix_sparse
 
     w = P.shape[0]
@@ -832,7 +836,8 @@ def mix_pytree_sharded(P, stacked, mesh, axis: str = "worker",
                     p_local, (0, src * b), (b, b)).astype(jnp.float32)
                 if ss is not None:           # dequant: scale into columns
                     blk = blk * ss[None, :]
-                acc = acc + blk @ qq.astype(jnp.float32)
+                acc = acc + jnp.matmul(blk, qq.astype(jnp.float32),
+                                       precision=MIX_PRECISION)
             outs.append(acc)
         return tuple(outs)
 
@@ -843,8 +848,8 @@ def mix_pytree_sharded(P, stacked, mesh, axis: str = "worker",
     if has_scale:
         in_specs = in_specs + tuple(Ps(axis) for _ in leaves)
         operands += scales
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=specs, check_vma=False)
     out_leaves = fn(Pp, idx_j, valid_j, *operands)
     out_leaves = [o[:w].reshape(x.shape).astype(x.dtype)
                   for o, x in zip(out_leaves, leaves)]

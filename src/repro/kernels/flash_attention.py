@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -86,7 +88,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     static_argnames=("causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret=None):
     """q,k,v: [BH, S, D]; S % block == 0 (ops.py pads). Returns [BH, S, D]."""
     bh, s, d = q.shape
     nq, nk = s // block_q, s // block_k
@@ -109,5 +111,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),     # running max m
             pltpu.VMEM((block_q,), jnp.float32),     # running sum l
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

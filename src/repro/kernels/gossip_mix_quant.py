@@ -4,30 +4,26 @@ The quantized gossip wire format (core/gossip.py) ships each worker's
 flattened model row as int8 with ONE fp32 scale per row:
 
     q:     [W, F] int8 — round(row / scale), clipped to ±127
-    scale: [W, 1] f32  — max|row| / 127 (symmetric, per row)
+    scale: [W] f32     — max|row| / 127 (symmetric, per row)
 
 The naive lowering dequantizes the whole stack to fp32 HBM
 (``q.astype(f32) * scale``) and then runs the sparse mixing kernel — a full
 extra fp32 stack write+read that erases most of the 4× wire-byte win. This
-kernel fuses the two: it streams the INT8 stack through VMEM in (W, BF)
-tiles and applies the per-row scales inside the padded-CSR gather-mix
+op fuses the two:
 
     out[i] = Σ_k val[i, k] · scale[idx[i, k]] · q[idx[i, k], :]
 
-so fp32 rows exist only tile-at-a-time in VMEM, never materialized in HBM.
-Layout mirrors gossip_mix_sparse:
-
-* idx/val/scale stay resident in VMEM for the whole grid (one load — they
-  are [W, K] / [W, 1], tiny next to the stack).
-* Per tile, the dequant scales are folded into the CSR weights ONCE
-  (``sval[i, k] = val[i, k] · scale[idx[i, k]]``, a [W, K] VPU op) so the
-  inner loop is exactly the sparse kernel's K gather+FMA chain — the
-  dequant costs one extra [W, K] multiply per tile, not per element.
+* The dequant scales are folded into the CSR weights ONCE, before the
+  kernel (``sval[i, k] = val[i, k] · scale[idx[i, k]]``, a [W, K] op) so
+  the inner loop is exactly the sparse kernel's K load+FMA chain.
+* The INT8 stack streams from HBM; each (W, BF) block is widened to fp32
+  once in VMEM (``gossip_mix_sparse.gather_mix``), so fp32 rows exist only
+  block-at-a-time on chip, never materialized in HBM.
 * Accumulation is fp32; ``out_dtype`` sets the store dtype (the engine's
   parameter dtype, so the wire cast never leaks out).
 
-TPU follow-up (ROADMAP): keep the int8 tile un-widened in VMEM and let the
-VPU widen during the FMA; interpret mode widens the tile once up front.
+TPU follow-up (ROADMAP): keep the int8 block un-widened in VMEM and let
+the VPU widen during the FMA.
 
 The pure-jnp contract is ``repro.kernels.ref.gossip_mix_quant_ref``.
 """
@@ -37,54 +33,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.gossip_mix_sparse import DEFAULT_BLOCK_F, UNROLL_MAX_K
-
-
-def _kernel(idx_ref, val_ref, scale_ref, q_ref, o_ref):
-    stack = q_ref[...].astype(jnp.float32)            # [W, BF] tile
-    idx = idx_ref[...]                                # [W, K]
-    val = val_ref[...].astype(jnp.float32)            # [W, K]
-    scale = scale_ref[...][:, 0]                      # [W]
-    sval = val * jnp.take(scale, idx)                 # dequant folded once
-    k_slots = idx.shape[1]
-
-    def body(k, acc):
-        rows = jnp.take(stack, idx[:, k], axis=0)     # [W, BF] gather
-        return acc + sval[:, k][:, None] * rows
-
-    acc = jnp.zeros(stack.shape, jnp.float32)
-    if k_slots <= UNROLL_MAX_K:
-        for k in range(k_slots):
-            acc = body(k, acc)
-    else:
-        acc = jax.lax.fori_loop(0, k_slots, body, acc)
-    o_ref[...] = acc.astype(o_ref.dtype)
+from repro.kernels.gossip_mix_sparse import DEFAULT_BLOCK_F, gather_mix
 
 
 @functools.partial(jax.jit,
                    static_argnames=("out_dtype", "block_f", "interpret"))
 def gossip_mix_quant_pallas(idx, val, scale, q, *, out_dtype=jnp.float32,
                             block_f: int = DEFAULT_BLOCK_F,
-                            interpret: bool = True):
+                            interpret=None):
     """idx: [W, K] int32; val: [W, K]; scale: [W] or [W, 1] f32;
-    q: [W, F] int8 with F % block_f == 0 (ops.py pads).
-    Returns [W, F] in ``out_dtype``."""
-    n, f = q.shape
-    k = idx.shape[1]
-    grid = (f // block_f,)
-    return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, k), lambda i: (0, 0)),        # idx resident
-            pl.BlockSpec((n, k), lambda i: (0, 0)),        # val resident
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),        # scales resident
-            pl.BlockSpec((n, block_f), lambda i: (0, i)),  # stream int8
-        ],
-        out_specs=pl.BlockSpec((n, block_f), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, f), out_dtype),
-        interpret=interpret,
-    )(idx.astype(jnp.int32), val.astype(jnp.float32),
-      scale.reshape(n, 1).astype(jnp.float32), q)
+    q: [W, F] int8. Returns [W, F] in ``out_dtype``."""
+    scale = scale.reshape(-1).astype(jnp.float32)
+    sval = val.astype(jnp.float32) * jnp.take(scale, idx)
+    return gather_mix(idx, sval, q, out_dtype=out_dtype, block_f=block_f,
+                      interpret=interpret)

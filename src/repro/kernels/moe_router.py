@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import resolve_interpret
+
 
 def _kernel(logits_ref, gates_ref, idx_ref, *, k: int):
     x = logits_ref[...].astype(jnp.float32)               # [BT, E]
@@ -43,7 +45,7 @@ def _kernel(logits_ref, gates_ref, idx_ref, *, k: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "block_t", "interpret"))
 def moe_router_pallas(logits, *, k: int, block_t: int = 256,
-                      interpret: bool = True):
+                      interpret=None):
     """logits: [T, E], T % block_t == 0 (ops.py pads)."""
     t, e = logits.shape
     grid = (t // block_t,)
@@ -59,6 +61,6 @@ def moe_router_pallas(logits, *, k: int, block_t: int = 256,
             jax.ShapeDtypeStruct((t, k), jnp.float32),
             jax.ShapeDtypeStruct((t, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(logits)
     return gates, idx

@@ -1,6 +1,6 @@
-"""jit'd public wrappers around the Pallas kernels: shape padding, layout
-glue, and CPU-interpret defaults (TPU is the target; this container
-validates via interpret=True).
+"""jit'd public wrappers around the Pallas kernels: shape padding and
+layout glue. Every kernel runs compiled on TPU and interpreted on the CPU
+(``kernels.common.resolve_interpret`` — the one place that choice lives).
 """
 from __future__ import annotations
 
@@ -11,9 +11,12 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_chunk import ssd_chunk_pallas
-from repro.kernels.gossip_mix import gossip_mix_pallas
-from repro.kernels.gossip_mix_sparse import gossip_mix_sparse_pallas
-from repro.kernels.gossip_mix_quant import gossip_mix_quant_pallas
+# the gossip kernels pad to their own tile plans: exported as they are
+from repro.kernels.gossip_mix import gossip_mix_pallas as gossip_mix  # noqa: F401
+from repro.kernels.gossip_mix_quant import (  # noqa: F401
+    gossip_mix_quant_pallas as gossip_mix_quant)
+from repro.kernels.gossip_mix_sparse import (  # noqa: F401
+    gossip_mix_sparse_pallas as gossip_mix_sparse)
 from repro.kernels.moe_router import moe_router_pallas
 
 
@@ -36,49 +39,10 @@ def _pow2_block(n: int, block: int) -> int:
     return max(16, min(cap, want))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "block_f", "interpret"))
-def gossip_mix(P, w, *, out_dtype=None, block_f: int = 2048,
-               interpret: bool = True):
-    """P: [W, W]; w: [W, F] (any F — padded internally). ``out_dtype``
-    overrides the store dtype (default: w's — fp32 accum either way)."""
-    wp, pad = _pad_to(w, 1, block_f)
-    out = gossip_mix_pallas(P, wp, out_dtype=out_dtype, block_f=block_f,
-                            interpret=interpret)
-    return out[:, :w.shape[1]] if pad else out
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "block_f", "interpret"))
-def gossip_mix_sparse(idx, val, w, *, out_dtype=None, block_f: int = 2048,
-                      interpret: bool = True):
-    """Padded-CSR gossip: idx/val [W, K]; w [W, F] (any F — padded
-    internally). out[i] = sum_k val[i,k] * w[idx[i,k]]. ``out_dtype``
-    overrides the store dtype (default: w's — fp32 accum either way)."""
-    wp, pad = _pad_to(w, 1, block_f)
-    out = gossip_mix_sparse_pallas(idx, val, wp, out_dtype=out_dtype,
-                                   block_f=block_f, interpret=interpret)
-    return out[:, :w.shape[1]] if pad else out
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "block_f", "interpret"))
-def gossip_mix_quant(idx, val, scale, q, *, out_dtype=jnp.float32,
-                     block_f: int = 2048, interpret: bool = True):
-    """Fused int8 dequantize→mix: idx/val [W, K]; scale [W] f32; q [W, F]
-    int8 (any F — padded internally; int8 zero padding dequantizes to 0).
-    out[i] = sum_k val[i,k] * scale[idx[i,k]] * q[idx[i,k]]."""
-    qp, pad = _pad_to(q, 1, block_f)
-    out = gossip_mix_quant_pallas(idx, val, scale, qp, out_dtype=out_dtype,
-                                  block_f=block_f, interpret=interpret)
-    return out[:, :q.shape[1]] if pad else out
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
+                                             "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    block_q: int = 128, block_k: int = 128):
     """q,k,v: [B, H, S, D]. Pads S to a block multiple; padded kv rows are
     masked out by the causal mask (they sit after every real query)."""
     b, h, s, d = q.shape
@@ -90,25 +54,21 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kf, _ = _pad_to(kf, 1, bq)
     vf, _ = _pad_to(vf, 1, bq)
     out = flash_attention_pallas(qf, kf, vf, causal=causal, window=window,
-                                 block_q=bq, block_k=bk,
-                                 interpret=interpret)
+                                 block_q=bq, block_k=bk)
     out = out[:, :s] if pad else out
     return out.reshape(b, h, s, d)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_t", "interpret"))
-def moe_router_topk(logits, k: int, *, block_t: int = 256,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("k", "block_t"))
+def moe_router_topk(logits, k: int, *, block_t: int = 256):
     """logits: [T, E] -> (gates [T, k] fp32, idx [T, k] int32)."""
     lp, pad = _pad_to(logits, 0, block_t)
-    gates, idx = moe_router_pallas(lp, k=k, block_t=block_t,
-                                   interpret=interpret)
+    gates, idx = moe_router_pallas(lp, k=k, block_t=block_t)
     if pad:
         gates, idx = gates[:logits.shape[0]], idx[:logits.shape[0]]
     return gates, idx
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk(C, B, acum, dt, x, *, interpret: bool = True):
+def ssd_chunk(C, B, acum, dt, x):
     """Fused SSD intra-chunk op. See ssd_chunk.py for shapes."""
-    return ssd_chunk_pallas(C, B, acum, dt, x, interpret=interpret)
+    return ssd_chunk_pallas(C, B, acum, dt, x)

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import resolve_interpret
+
 
 def _kernel(c_ref, b_ref, acum_ref, dt_ref, x_ref, o_ref):
     c = c_ref[0].astype(jnp.float32)                  # [T, N]
@@ -41,7 +43,7 @@ def _kernel(c_ref, b_ref, acum_ref, dt_ref, x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(C, B, acum, dt, x, *, interpret: bool = True):
+def ssd_chunk_pallas(C, B, acum, dt, x, *, interpret=None):
     """C,B: [G, T, N]; acum,dt: [G, H, T]; x: [G, H, T, P] ->
     y: [G, H, T, P]   (G = batch·num_chunks)."""
     g, t, n = C.shape
@@ -59,5 +61,5 @@ def ssd_chunk_pallas(C, B, acum, dt, x, *, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, 1, t, p), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, h, t, p), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(C, B, acum, dt, x)

@@ -1,15 +1,19 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent by lowering
 and compiling every (architecture × input shape × mesh) combination on the
 production mesh, with ShapeDtypeStruct inputs (no allocation), and dump
 memory/cost/roofline data for EXPERIMENTS.md.
 
+A CPU tool by design: it pins JAX to the CPU platform with 512 host
+devices before JAX initialises, so it never takes a chip. It exits 1 when
+any (arch, shape) pair failed.
+
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 """
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import dataclasses
@@ -408,7 +412,7 @@ def run_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
     return result
 
 
-def main():
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="")
     ap.add_argument("--shape", default="")
@@ -448,6 +452,7 @@ def main():
     else:
         pairs.append((args.arch, args.shape))
 
+    failed = []
     for arch, shape in pairs:
         tag = f"{arch}_{shape}_{'multi' if args.multi_pod else 'single'}"
         if args.fedavg_baseline:
@@ -469,9 +474,15 @@ def main():
             traceback.print_exc()
             res = {"arch": arch, "shape": shape, "status": "FAILED",
                    "error": f"{type(e).__name__}: {e}"}
+            failed.append(tag)
         with open(out_path, "w") as f:
             json.dump(res, f, indent=1)
+    if failed:
+        print(f"FAILED: {len(failed)} of {len(pairs)} pairs: "
+              f"{', '.join(failed)}")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -17,10 +17,31 @@ import re
 from dataclasses import dataclass, asdict
 from typing import Dict
 
-# v5e-class hardware constants (from the assignment)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect (4 ICI links of 50 GB/s).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {"bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bytes": 16e9,
+          "hbm_bw": 819e9, "ici_link_bw": 50e9, "ici_links": 4},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The published peaks of one chip; a kind not in ``PEAKS`` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
+
+# the dry-run prices the v5e production mesh
+PEAK_FLOPS = chip_peaks(V5E)["bf16_flops"]   # FLOP/s per chip
+HBM_BW = chip_peaks(V5E)["hbm_bw"]           # bytes/s per chip
+ICI_BW = chip_peaks(V5E)["ici_link_bw"]      # bytes/s per link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -30,8 +30,10 @@ Three modes:
   exceeds ceil(rounds / eval_every) — the gather/scatter-fused superstep
   contract the CI smoke gates.
 
-On this CPU container use tiny configs (e.g. --arch paper-small --debug-mesh)
-— the full meshes are exercised by dryrun.py.
+On a CPU use tiny configs (e.g. --arch paper-small --debug-mesh) — the
+full meshes are exercised by dryrun.py. On TPU chips, ``--fl`` builds a
+``pod=N, data=1, model=1`` mesh of the chips present when there is one per
+pod; ``chip_smoke.py`` drives this module that way.
 """
 from __future__ import annotations
 
@@ -62,18 +64,14 @@ def make_ledger(args, cfg, mode: str):
 
 
 def start_profile(args):
-    """``--profile DIR`` → start a jax.profiler trace (best-effort: warns
-    and continues when the profiler backend is unavailable)."""
+    """``--profile DIR`` → start a jax.profiler trace. A trace that cannot
+    start raises: a run asked to profile does not go on without one."""
     if not getattr(args, "profile", ""):
         return False
     import jax
 
-    try:
-        jax.profiler.start_trace(args.profile)
-        return True
-    except Exception as e:                       # pragma: no cover
-        print(f"--profile: trace unavailable ({e}); continuing")
-        return False
+    jax.profiler.start_trace(args.profile)
+    return True
 
 
 def stop_profile(args, started: bool):
@@ -81,11 +79,8 @@ def stop_profile(args, started: bool):
         return
     import jax
 
-    try:
-        jax.profiler.stop_trace()
-        print(f"profile trace written to {args.profile}")
-    except Exception as e:                       # pragma: no cover
-        print(f"--profile: stop_trace failed ({e})")
+    jax.profiler.stop_trace()
+    print(f"profile trace written to {args.profile}")
 
 
 def run_scenario_sim(args) -> int:
@@ -279,7 +274,7 @@ def run_cross_device_sim(args) -> int:
     return 0
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-small")
     ap.add_argument("--steps", type=int, default=20)
@@ -426,8 +421,12 @@ def main():
                          "devices on CPU; see docs/ARCHITECTURE.md "
                          "'Sharded worker axis'). Scenario runs exit 1 "
                          "on dispatch-parity violation")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None) -> int:
+    """Run the mode the flags select; returns the process exit code."""
+    args = parse_args(argv)
     if args.shard_workers > 1:
         # must land before ANY jax import — the sim paths import jax inside
         import os
@@ -435,25 +434,41 @@ def main():
             "XLA_FLAGS",
             f"--xla_force_host_platform_device_count={args.shard_workers}")
 
-    if args.cross_device:
-        raise SystemExit(run_cross_device_sim(args))
-
-    if args.scenario and not args.fl:
-        raise SystemExit(run_scenario_sim(args))
-
     if args.debug_mesh:
+        # the CPU-test route: host devices stand in for chips
         import os
         n = 4 * (args.pods if args.fl else 1)
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         os.environ.setdefault(
             "XLA_FLAGS", f"--xla_force_host_platform_device_count={n}")
 
+    from repro.cache import enable_compile_cache
+    enable_compile_cache()
+
+    if args.cross_device:
+        return run_cross_device_sim(args)
+    if args.scenario and not args.fl:
+        return run_scenario_sim(args)
+
+    losses = run_model(args)["losses"]
+    if not np.isfinite(losses).all():
+        print("FAIL: non-finite training loss")
+        return 1
+    return 0
+
+
+def run_model(args) -> dict:
+    """Train one model (or, with ``--fl``, one replica per pod that gossip
+    every ``--gossip-every`` steps). Returns ``{"params", "losses" [steps,
+    pods] (or [steps]), "step_s" [steps]}``; each step's seconds end at
+    its loss on the host, so they include that step's compiles."""
     import jax
     import jax.numpy as jnp
     from repro.config import ShapeConfig, reduced
     from repro.configs import get_config
     from repro.core.topology import make_topology
     from repro.data.loader import TokenBatcher
-    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.mesh import make_debug_mesh, make_pod_mesh
     from repro.launch.sharding_rules import base_rules
     from repro.launch.steps import (build_fl_train_step, build_train_step,
                                     input_specs)
@@ -467,9 +482,18 @@ def main():
     opt = make_optimizer(args.optimizer, args.lr)
     pods = args.pods if args.fl else 0
 
-    mesh = make_debug_mesh(pods=pods if args.fl else 0) if args.debug_mesh \
-        else None
+    if args.debug_mesh:
+        mesh = make_debug_mesh(pods=pods)
+    elif pods and (args.transport == "ppermute"
+                   or 1 < pods <= len(jax.devices())):
+        mesh = make_pod_mesh(pods)           # one chip per pod
+    else:
+        mesh = None
     rules = base_rules(multi_pod=bool(pods)) if mesh else {}
+    if pods and mesh:
+        # inside the vmap(spmd_axis_name="pod") body, constraints must not
+        # mention the pod axis — vmap supplies it for the batched dims
+        rules = {**rules, "batch": ("data",)}
     batcher = TokenBatcher(cfg.vocab_size, args.seq, args.batch)
 
     key = jax.random.PRNGKey(0)
@@ -491,12 +515,28 @@ def main():
             from repro.launch.steps import build_pod_gossip_step
             from repro.scenarios import compile_scenario, get_scenario
             from repro.scenarios.robust_agg import ROBUST_RULES
+            from repro.sharding import WorkerShards
+
+            def on_pods(fn, *a):
+                """fn(*a) built straight into its placement: leaves with
+                a leading pod axis row-sharded over the mesh's pod axis,
+                the rest replicated (no mesh: the default device)."""
+                if mesh is None:
+                    return fn(*a)
+                ws = WorkerShards(mesh, axis="pod")
+                out = jax.tree.map(
+                    lambda s: ws.row_sharding(len(s.shape))
+                    if s.shape and s.shape[0] == pods
+                    else ws.replicated(), jax.eval_shape(fn, *a))
+                return jax.jit(fn, out_shardings=out)(*a)
 
             stack = lambda t: jax.tree.map(
                 lambda x: jnp.stack([x] * pods), t)
-            params, opt_state = stack(params), stack(opt_state)
-            fl_step = jax.jit(build_fl_train_step(cfg, opt),
-                              donate_argnums=(0, 1))
+            params, opt_state = on_pods(
+                lambda p, o: (stack(p), stack(o)), params, opt_state)
+            fl_step = jax.jit(build_fl_train_step(
+                cfg, opt, spmd_axis_name="pod" if mesh else None),
+                donate_argnums=(0, 1))
             adj = make_topology("dense", pods, pods - 1)
             sizes = np.full(pods, args.batch)
 
@@ -562,11 +602,11 @@ def main():
                 transport=args.transport, mesh=mesh, scenario=scenario,
                 self_eval=self_eval)
             gossip = jax.jit(gossip_rnd, donate_argnums=(0, 1))
-            pstate = init_pod_state(
-                jax.random.PRNGKey(101), pods, params,
+            pstate = on_pods(lambda p: init_pod_state(
+                jax.random.PRNGKey(101), pods, p,
                 wire_error=uses_error_feedback(dcfg) and not robust,
                 time_machine=dcfg.time_machine,
-                sketch=sketch_shape(dcfg))
+                sketch=sketch_shape(dcfg)), params)
             print(f"--fl pod pipeline: transport={pod_tr.kind} "
                   f"wire={pod_tr.wire or 'fp32'} ef={pod_tr.use_ef} "
                   f"aggregation={args.aggregation} dts={dcfg.use_dts} "
@@ -581,6 +621,7 @@ def main():
                 if track_start else None
 
             losses = jnp.zeros((pods,))
+            hist, step_s = [], []
             for i in range(args.steps):
                 b = batcher.batch_at(i)
                 batch = {k: jnp.asarray(v).reshape(
@@ -593,22 +634,27 @@ def main():
                                             gossip_start)
                     if track_start:
                         gossip_start = jax.tree.map(jnp.copy, params)
+                hist.append(np.asarray(losses, np.float64))
+                step_s.append(time.time() - t0)
                 print(f"step {i:4d} losses="
-                      f"{[round(float(x), 4) for x in losses]} "
-                      f"({time.time() - t0:.2f}s)"
+                      f"{[round(float(x), 4) for x in hist[-1]]} "
+                      f"({step_s[-1]:.2f}s)"
                       + ("  [gossip]" if (i + 1) % args.gossip_every == 0
                          else ""))
         else:
             tstep = jax.jit(build_train_step(cfg, opt),
                             donate_argnums=(0, 1))
+            hist, step_s = [], []
             for i in range(args.steps):
                 b = batcher.batch_at(i)
                 batch = {k: jnp.asarray(v) for k, v in b.items()}
                 t0 = time.time()
                 params, opt_state, step, loss = tstep(params, opt_state,
                                                       step, batch)
-                print(f"step {i:4d} loss={float(loss):.4f} "
-                      f"({time.time() - t0:.2f}s)")
+                hist.append(float(loss))
+                step_s.append(time.time() - t0)
+                print(f"step {i:4d} loss={hist[-1]:.4f} "
+                      f"({step_s[-1]:.2f}s)")
 
     if args.checkpoint_dir:
         from repro.checkpoint import save_checkpoint
@@ -616,6 +662,8 @@ def main():
                                {"params": params, "opt": opt_state},
                                int(step))
         print("checkpoint saved:", path)
+    return {"params": params, "losses": np.asarray(hist),
+            "step_s": step_s}
 
 
 class _nullcontext:
@@ -627,4 +675,4 @@ class _nullcontext:
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

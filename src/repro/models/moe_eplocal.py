@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.config import ModelConfig
 from repro.models.moe import load_balance_loss
 from repro.sharding import _ctx
@@ -237,6 +236,6 @@ def moe_eplocal(params, cfg: ModelConfig, x, *, cap_factor: float = 1.25,
 
     # pass only the params the body uses (spec dict must match tree)
     used = {k: v for k, v in params.items() if k in pspec}
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(used, x)

@@ -63,7 +63,8 @@ def defta_state_digest(st, stats=None):
     return d
 
 
-def main():
+def capture() -> dict:
+    """Every golden entry, computed by the engines as they stand."""
     import dataclasses
     goldens = {}
     data, task, cfg, train = setup()
@@ -112,7 +113,11 @@ def main():
     st = run_fedavg(jax.random.PRNGKey(0), task, cfg, train, data, epochs=4,
                     sample_workers=2)
     goldens["fedavg_sampled"] = {"server": tree_digest(st.server)}
+    return goldens
 
+
+def main():
+    goldens = capture()
     with open(OUT, "w") as fh:
         json.dump(goldens, fh, indent=1, sort_keys=True)
     print(f"wrote {OUT}")

@@ -160,11 +160,12 @@ def test_costing_correction_matches_unrolled():
         from repro.config import reduced, SHAPES, ShapeConfig
         from repro.configs import get_config
         from repro.launch.costing import train_cost
+        from repro.launch.mesh import auto_mesh
         from repro.launch.sharding_rules import base_rules
         from repro.launch.steps import build_train_step, input_specs, abstract_state
         from repro.sharding import logical_rules
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = auto_mesh((2, 2), ("data", "model"))
         rules = base_rules(False)
         cfg = dataclasses.replace(
             reduced(get_config("granite-3-2b"), num_layers=6, d_model=256),

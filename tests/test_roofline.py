@@ -1,9 +1,10 @@
 """Roofline analyzer units: HLO collective-bytes parser + term math."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.launch.roofline import (ICI_BW, analyze, collective_bytes,
-                                   shape_bytes)
+from repro.launch.roofline import (HBM_BW, ICI_BW, PEAK_FLOPS, analyze,
+                                   chip_peaks, collective_bytes, shape_bytes)
 
 HLO = """
 HloModule jit_step
@@ -62,3 +63,19 @@ def test_analyze_collective_override():
                 coll_override={"all-to-all": ICI_BW})
     assert abs(r.t_collective - 1.0) < 1e-9
     assert r.bottleneck == "collective"
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """v5e's published peaks under the kind JAX reports for the chip; the
+    dry-run's constants are that entry."""
+    p = chip_peaks("TPU v5 lite")
+    assert p["bf16_flops"] == 197e12 and p["int8_ops"] == 393e12
+    assert p["hbm_bytes"] == 16e9 and p["hbm_bw"] == 819e9
+    assert p["ici_link_bw"] * p["ici_links"] * 8 == 1600e9   # 1,600 Gbit/s
+    assert (PEAK_FLOPS, HBM_BW, ICI_BW) == (
+        p["bf16_flops"], p["hbm_bw"], p["ici_link_bw"])
+
+
+def test_chip_peaks_unknown_kind_is_an_error():
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
