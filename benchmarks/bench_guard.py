@@ -45,7 +45,8 @@ fails (exit 1) when the headline wins regress:
   row's realized cross-shard ring bytes must equal the independent
   ``roofline.sharded_ring_bytes`` re-derivation, and the sharded engine
   must stay on the ceil(epochs/eval_every) superstep dispatch budget at
-  every shard count (layout may not break scan fusion);
+  every shard count (layout may not break scan fusion); a sweep with no
+  sharded row (fewer than 4 devices) fails rather than pass unchecked;
 * the privacy wire must keep its contracts: a clean secagg run lands
   within 0.01 of the unmasked run (the in-ring OTP decodes bit for bit),
   the realized mask-byte accounting equals the independent
@@ -323,6 +324,12 @@ def check(baseline, fresh, tolerance):
                     f"dispatches, layout is not allowed to break fusion")
         if not ws.get("rows"):
             failures.append("w_scaling entry has no rows")
+        if not any(r["shards"] > 1 for r in ws.get("rows", [])):
+            failures.append(
+                f"w_scaling has no sharded row (shard counts "
+                f"{ws.get('skipped_shard_counts', '?')} skipped): the ring-"
+                f"byte and sharded-dispatch contracts went unchecked — run "
+                f"the bench with at least 4 devices")
 
     pv = fresh.get("privacy")
     if not pv:

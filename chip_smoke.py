@@ -38,9 +38,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
 NUM_CLASSES = 10
-# the sharded-vs-single-device bound on the int8 wire
-# (tests/test_shard_engine.py)
-SHARD_TOL_INT8 = 5e-3
 
 
 def log(tag: str, **kv):
@@ -48,12 +45,17 @@ def log(tag: str, **kv):
           flush=True)
 
 
-def peak_bytes():
-    """Device 0's peak bytes in use so far (None where not reported)."""
+def memory_stats() -> dict:
+    """Device 0's allocator statistics (empty where not reported)."""
     import jax
 
-    stats = jax.devices()[0].memory_stats() or {}
-    return stats.get("peak_bytes_in_use")
+    return jax.devices()[0].memory_stats() or {}
+
+
+def peak_bytes():
+    """Device 0's peak bytes in use since the process started: a phase's
+    own peak only when no earlier phase went higher."""
+    return memory_stats().get("peak_bytes_in_use")
 
 
 def require_tpu(count: int):
@@ -127,7 +129,7 @@ def phase_defta(*, workers: int = 20, hw: int = 28, width: int = 16,
     if len(sup) >= 2:
         log("A", compile_s_est=f"{sup[0] - sup[-1]:.3f}",
             round_s=f"{sup[-1] / min(eval_every, epochs):.5f}")
-    log("A", peak_bytes_in_use=peak_bytes())
+    log("A", process_peak_bytes_in_use=peak_bytes())
     log("A", eval=[(e, round(m, 4), round(s, 4)) for e, m, s in hist])
     log("A", final_acc=f"{acc:.4f}", acc_std=f"{acc_std:.4f}",
         chance=1.0 / NUM_CLASSES,
@@ -153,7 +155,8 @@ FL_ARGS = ["--fl", "--arch", "qwen3-0.6b", "--optimizer", "adafactor",
 
 def run_train(argv, phase: str):
     """``train.run_model`` on ``argv``; logs its numbers, returns
-    (result, failed checks)."""
+    (result, failed checks). With ``--memory-report`` in ``argv`` each
+    step program's compiled peak must fit the device's memory limit."""
     import numpy as np
 
     from repro.launch import train
@@ -166,16 +169,24 @@ def run_train(argv, phase: str):
         # step 0 compiles the train step, step 1 the gossip round
         log(phase, train_compile_s_est=f"{step_s[0] - step_s[2]:.3f}",
             gossip_compile_s_est=f"{step_s[1] - step_s[3]:.3f}")
-    log(phase, peak_bytes_in_use=peak_bytes())
+    limit = memory_stats().get("bytes_limit")
+    for name, mem in res["memory"].items():
+        log(phase, program=name, bytes_limit=limit, **mem)
+    log(phase, process_peak_bytes_in_use=peak_bytes())
     log(phase, losses=np.round(losses, 5).tolist())
     fails = []
     if not np.isfinite(losses).all():
         fails.append(f"{phase}: non-finite loss")
+    for name, mem in res["memory"].items():
+        if limit and mem["peak"] > limit:
+            fails.append(f"{phase}: {name} needs {mem['peak']} B, the "
+                         f"device holds {limit}")
     return res, fails
 
 
 def phase_train(extra=()):
-    return run_train(FL_ARGS + ["--pods", "2"] + list(extra), "B")[1]
+    return run_train(FL_ARGS + ["--pods", "2", "--memory-report"]
+                     + list(extra), "B")[1]
 
 
 # ---------------------------------------------------------------------------
@@ -185,17 +196,16 @@ def phase_train(extra=()):
 def phase_shards(*, workers: int = 10_000, shards: int = 4,
                  epochs: int = 2, seed: int = 0):
     """The sharded engine's 10k-worker world (tests/test_shard_engine.py),
-    sparse + int8: ``shards=4`` against ``shards=None`` in this process;
-    states agree to that file's int8 bound and each run keeps the
-    dispatch budget. Both runs take f32 matmuls at full precision, the
-    regime that bound was set in: at TPU's default the local-training
-    matmuls round differently in the two layouts, and the int8 wire turns
-    such ulp differences into whole quantization steps."""
+    sparse + int8: ``shards=4`` against ``shards=None`` in this process,
+    both at the platform's default matmul precision; states agree to the
+    int8 wire's sharded-parity bound and each run keeps the dispatch
+    budget."""
     import jax
     import numpy as np
 
     from repro.config import DeFTAConfig, TrainConfig
     from repro.core.defta import run_defta
+    from repro.core.gossip import SHARD_PARITY_TOL
     from repro.core.tasks import mlp_task
     from repro.data.synthetic import federated_dataset
     from repro.telemetry import RunLedger
@@ -206,16 +216,15 @@ def phase_shards(*, workers: int = 10_000, shards: int = 4,
     data = federated_dataset("vector", workers, np.random.default_rng(seed),
                              n_per_worker=8, alpha=0.5)
     task = mlp_task(32, NUM_CLASSES)
+    tol = SHARD_PARITY_TOL["int8"]
     out = {}
     for name, n in (("one", None), ("sharded", shards)):
         led = RunLedger()
         t0 = time.perf_counter()
-        with jax.default_matmul_precision("highest"):
-            st, *_ = run_defta(jax.random.PRNGKey(seed), task, cfg, train,
-                               data, epochs=epochs, eval_every=epochs,
-                               gossip_backend="sparse", ledger=led,
-                               shards=n)
-            jax.block_until_ready(st)
+        st, *_ = run_defta(jax.random.PRNGKey(seed), task, cfg, train,
+                           data, epochs=epochs, eval_every=epochs,
+                           gossip_backend="sparse", ledger=led, shards=n)
+        jax.block_until_ready(st)
         out[name] = (st, led)
         log("S", run=name, shards=n or 1, run_s=f"{time.perf_counter() - t0:.3f}",
             dispatches=led.dispatches,
@@ -225,11 +234,10 @@ def phase_shards(*, workers: int = 10_000, shards: int = 4,
             "backup": max_err(s1.backup, s4.backup),
             "conf": max_err(s1.conf, s4.conf),
             "best_loss": max_err(s1.best_loss, s4.best_loss)}
-    log("S", **{f"err_{k}": f"{v:.3e}" for k, v in errs.items()},
-        tol=SHARD_TOL_INT8)
-    log("S", peak_bytes_in_use=peak_bytes())
-    fails = [f"S: {k} differs by {v:.3e} > {SHARD_TOL_INT8}"
-             for k, v in errs.items() if not v <= SHARD_TOL_INT8]
+    log("S", **{f"err_{k}": f"{v:.3e}" for k, v in errs.items()}, tol=tol)
+    log("S", process_peak_bytes_in_use=peak_bytes())
+    fails = [f"S: {k} differs by {v:.3e} > {tol}"
+             for k, v in errs.items() if not v <= tol]
     if not (np.asarray(s1.epoch) == np.asarray(s4.epoch)).all():
         fails.append("S: per-worker epochs differ")
     for name, led in (("one", l1), ("sharded", l4)):
@@ -291,7 +299,7 @@ def main(argv=None) -> int:
         return 1
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
-        "count": len(devs)}}), flush=True)
+        "count": count}}), flush=True)
     return 0
 
 

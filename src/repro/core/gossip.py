@@ -61,6 +61,12 @@ SPARSE_DENSITY_THRESHOLD = 0.25
 # matmuls) drift apart; the CPU computes f32 either way.
 MIX_PRECISION = jax.lax.Precision.HIGHEST
 
+# How far a worker-sharded run (``run_defta(shards=N)``) may drift from the
+# one-device run of the same seed, per state leaf, by wire. The layouts
+# differ only in f32 summation order; the int8 wire can turn such an ulp
+# into one quantization step on a few rows.
+SHARD_PARITY_TOL = {None: 5e-4, "int8": 5e-3}
+
 # bytes per parameter on the wire, by format (int8 adds 4 B/row of scales,
 # accounted in launch.roofline.gossip_wire_bytes)
 WIRE_BYTES = {None: 4, "fp32": 4, "bf16": 2, "int8": 1}

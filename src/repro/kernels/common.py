@@ -21,6 +21,7 @@ VMEM_BUDGET = 12 << 20
 # Most VMEM a kernel may ask for (v5e holds 128 MiB per core).
 VMEM_CAP = 100 << 20
 LANES = 128
+ROW_BLOCK = 128      # output rows per gossip-kernel grid step (at most)
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
@@ -44,6 +45,16 @@ def pow2_lanes(n: int, cap: int) -> int:
     cap = max(LANES, 1 << (max(cap, 1).bit_length() - 1))
     want = max(LANES, 1 << max(n - 1, 1).bit_length())
     return min(cap, want)
+
+
+def fit_block_f(f: int, block_f: int, footprint):
+    """(BF, its VMEM bytes): the widest parameter block, from
+    ``pow2_lanes(f, block_f)`` halving down to 128 lanes, whose grid step
+    ``footprint(bf)`` fits ``VMEM_BUDGET`` (128 lanes when none does)."""
+    bf = pow2_lanes(f, block_f)
+    while bf > LANES and footprint(bf) > VMEM_BUDGET:
+        bf //= 2
+    return bf, footprint(bf)
 
 
 def compiler_params(footprint: int, interpret: bool, what: str):

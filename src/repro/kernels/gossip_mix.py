@@ -24,11 +24,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (LANES, VMEM_BUDGET, compiler_params,
-                                  pow2_lanes, resolve_interpret)
+from repro.kernels.common import (ROW_BLOCK, compiler_params, fit_block_f,
+                                  resolve_interpret)
 
 DEFAULT_BLOCK_F = 2048
-ROW_BLOCK = 128      # rows of P (and of the output) per grid step
 
 
 def plan_tiles(w: int, f: int, itemsize: int, out_itemsize: int,
@@ -42,10 +41,7 @@ def plan_tiles(w: int, f: int, itemsize: int, out_itemsize: int,
         return (2 * br * w * 4 + 2 * w * bf * itemsize
                 + (4 * w * bf if widen else 0) + 2 * br * bf * out_itemsize)
 
-    bf = pow2_lanes(f, block_f)
-    while bf > LANES and footprint(bf) > VMEM_BUDGET:
-        bf //= 2
-    return br, nr, bf, footprint(bf)
+    return (br, nr) + fit_block_f(f, block_f, footprint)
 
 
 def _kernel(p_ref, w_ref, o_ref, *widened):

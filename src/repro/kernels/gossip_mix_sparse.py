@@ -37,8 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (LANES, VMEM_BUDGET, compiler_params,
-                                  pow2_lanes, resolve_interpret)
+from repro.kernels.common import (ROW_BLOCK, compiler_params, fit_block_f,
+                                  resolve_interpret)
 
 DEFAULT_BLOCK_F = 2048
 
@@ -49,7 +49,6 @@ DEFAULT_BLOCK_F = 2048
 # anyway — a fori_loop bounds compile time.
 UNROLL_MAX_K = 128
 
-ROW_BLOCK = 128      # output rows per grid step (at most)
 SMEM_SLOTS = 8192    # (row, peer) slots of idx/val one step keeps in SMEM
 SMEM_TILE = 1024     # Mosaic's tiling of a long rank-1 SMEM operand
 
@@ -70,10 +69,7 @@ def plan_tiles(w: int, k: int, f: int, itemsize: int, block_f: int):
         return (2 * w * bf * itemsize + (4 * w * bf if widen else 0)
                 + 2 * br * bf * 4)
 
-    bf = pow2_lanes(f, block_f)
-    while bf > LANES and footprint(bf) > VMEM_BUDGET:
-        bf //= 2
-    return br, nr, slots, bf, footprint(bf)
+    return (br, nr, slots) + fit_block_f(f, block_f, footprint)
 
 
 def _kernel(idx_ref, val_ref, w_ref, o_ref, *widened, k_slots, rows):

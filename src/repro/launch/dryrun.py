@@ -355,7 +355,7 @@ def run_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
                       f"ppermute ring {sc['ring_bytes_scenario'] / 1e9:.2f}"
                       f" GB/round (nnz row selection)")
 
-    mem = compiled.memory_analysis()
+    mem = rf.compiled_bytes(compiled)
     # scan-aware correction: XLA counts while bodies once (see costing.py)
     from repro.launch.costing import corrected_cost, train_cost
     # FL steps are pod-independent: cost them on the single-pod submesh
@@ -371,10 +371,7 @@ def run_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
             flops_dev, bytes_dev, coll_dev = corrected_cost(
                 compiled, cfg, shape, mesh, rules, fl_pods=fl_pods,
                 moe_strategy=moe_strategy if cfg.moe else "grouped")
-    peak_bytes = getattr(mem, "temp_size_in_bytes", 0) + \
-        getattr(mem, "argument_size_in_bytes", 0) + \
-        getattr(mem, "output_size_in_bytes", 0) - \
-        getattr(mem, "alias_size_in_bytes", 0)
+    peak_bytes = mem["peak"]
     roof = rf.analyze(arch, shape_name, "multi" if multi_pod else "single",
                       chips, {"flops": flops_dev, "bytes accessed": bytes_dev},
                       "", rf.model_flops_estimate(cfg, shape),
@@ -391,10 +388,10 @@ def run_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
         "active_params_b": cfg.param_count(active_only=True) / 1e9,
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
         "memory": {
-            "temp_gb": getattr(mem, "temp_size_in_bytes", 0) / 2**30,
-            "arg_gb": getattr(mem, "argument_size_in_bytes", 0) / 2**30,
-            "out_gb": getattr(mem, "output_size_in_bytes", 0) / 2**30,
-            "alias_gb": getattr(mem, "alias_size_in_bytes", 0) / 2**30,
+            "temp_gb": mem["temp"] / 2**30,
+            "arg_gb": mem["argument"] / 2**30,
+            "out_gb": mem["output"] / 2**30,
+            "alias_gb": mem["alias"] / 2**30,
             "peak_per_device_gb": peak_bytes / 2**30,
         },
         "roofline": roof.to_dict(),

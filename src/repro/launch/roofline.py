@@ -189,6 +189,18 @@ def dp_epsilon(sigma: float, rounds: int, *, delta: float = 1e-5) -> float:
     return rounds * math.sqrt(2.0 * math.log(1.25 / delta)) / sigma
 
 
+def compiled_bytes(compiled) -> Dict[str, int]:
+    """A compiled program's device footprint from XLA's
+    ``memory_analysis``: argument, output, temporary and aliased (donated)
+    bytes, and ``peak`` = argument + output + temp - alias."""
+    mem = compiled.memory_analysis()
+    out = {k: int(getattr(mem, f"{k}_size_in_bytes", 0))
+           for k in ("argument", "output", "temp", "alias")}
+    out["peak"] = (out["argument"] + out["output"] + out["temp"]
+                   - out["alias"])
+    return out
+
+
 def shape_bytes(shape_str: str) -> int:
     """Bytes of one HLO shape literal like ``bf16[16,512,128]``."""
     m = _SHAPE_RE.match(shape_str)

@@ -326,6 +326,10 @@ def parse_args(argv=None):
                          "adopting the mix")
     ap.add_argument("--debug-mesh", action="store_true",
                     help="2x2(x pods) host-device mesh for CPU")
+    ap.add_argument("--memory-report", action="store_true",
+                    help="after the run, print the compiled memory "
+                         "footprint (XLA memory_analysis) of the train "
+                         "step and, with --fl, of the gossip round")
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
@@ -460,8 +464,11 @@ def main(argv=None) -> int:
 def run_model(args) -> dict:
     """Train one model (or, with ``--fl``, one replica per pod that gossip
     every ``--gossip-every`` steps). Returns ``{"params", "losses" [steps,
-    pods] (or [steps]), "step_s" [steps]}``; each step's seconds end at
-    its loss on the host, so they include that step's compiles."""
+    pods] (or [steps]), "step_s" [steps], "memory"}``; each step's seconds
+    end at its loss on the host, so they include that step's compiles.
+    ``memory`` maps each step program to its compiled footprint
+    (``roofline.compiled_bytes``) with ``--memory-report``, else it is
+    empty."""
     import jax
     import jax.numpy as jnp
     from repro.config import ShapeConfig, reduced
@@ -469,6 +476,7 @@ def run_model(args) -> dict:
     from repro.core.topology import make_topology
     from repro.data.loader import TokenBatcher
     from repro.launch.mesh import make_debug_mesh, make_pod_mesh
+    from repro.launch.roofline import compiled_bytes
     from repro.launch.sharding_rules import base_rules
     from repro.launch.steps import (build_fl_train_step, build_train_step,
                                     input_specs)
@@ -501,6 +509,7 @@ def run_model(args) -> dict:
     opt_state = opt.init(params)
     step = jnp.zeros((), jnp.int32)
 
+    memory = {}
     ctx = logical_rules(mesh, rules) if mesh else _nullcontext()
     with (mesh if mesh else _nullcontext()), ctx:
         if args.fl:
@@ -641,6 +650,11 @@ def run_model(args) -> dict:
                       f"({step_s[-1]:.2f}s)"
                       + ("  [gossip]" if (i + 1) % args.gossip_every == 0
                          else ""))
+            if args.memory_report:
+                memory["train_step"] = compiled_bytes(fl_step.lower(
+                    params, opt_state, step, batch).compile())
+                memory["gossip_round"] = compiled_bytes(gossip.lower(
+                    pstate, params, losses, gossip_start).compile())
         else:
             tstep = jax.jit(build_train_step(cfg, opt),
                             donate_argnums=(0, 1))
@@ -655,6 +669,12 @@ def run_model(args) -> dict:
                 step_s.append(time.time() - t0)
                 print(f"step {i:4d} loss={hist[-1]:.4f} "
                       f"({step_s[-1]:.2f}s)")
+            if args.memory_report:
+                memory["train_step"] = compiled_bytes(tstep.lower(
+                    params, opt_state, step, batch).compile())
+    for name, mem in memory.items():
+        print(f"memory {name}: " + " ".join(
+            f"{k}={v}" for k, v in mem.items()))
 
     if args.checkpoint_dir:
         from repro.checkpoint import save_checkpoint
@@ -663,7 +683,7 @@ def run_model(args) -> dict:
                                int(step))
         print("checkpoint saved:", path)
     return {"params": params, "losses": np.asarray(hist),
-            "step_s": step_s}
+            "step_s": step_s, "memory": memory}
 
 
 class _nullcontext:

@@ -33,6 +33,20 @@ def test_gossip_mix_row_stochastic_preserves_constant():
                                rtol=1e-5)
 
 
+@pytest.mark.parametrize("f,block_f,per_lane,want", [
+    (100, 2048, 1, 128),                # narrow leaf: one 128-lane block
+    (100_000, 2048, 1, 2048),           # wide leaf, small rows: the cap
+    (100_000, 2048, 4096, 2048),        # 2048 * 4096 B = 8 MiB fits
+    (100_000, 2048, 8192, 1024),        # 16 MiB does not: halve once
+    (100_000, 2048, 1 << 20, 128),      # nothing fits: floor of 128 lanes
+])
+def test_fit_block_f_halves_to_the_vmem_budget(f, block_f, per_lane, want):
+    from repro.kernels.common import LANES, VMEM_BUDGET, fit_block_f
+    bf, nbytes = fit_block_f(f, block_f, lambda b: b * per_lane)
+    assert (bf, nbytes) == (want, want * per_lane)
+    assert nbytes <= VMEM_BUDGET or bf == LANES
+
+
 @pytest.mark.parametrize("b,h,s,d", [(2, 4, 256, 64), (1, 2, 128, 32),
                                      (2, 2, 384, 128), (1, 8, 512, 64)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 128),

@@ -4,7 +4,8 @@ import numpy as np
 import pytest
 
 from repro.launch.roofline import (HBM_BW, ICI_BW, PEAK_FLOPS, analyze,
-                                   chip_peaks, collective_bytes, shape_bytes)
+                                   chip_peaks, collective_bytes,
+                                   compiled_bytes, shape_bytes)
 
 HLO = """
 HloModule jit_step
@@ -35,6 +36,20 @@ def test_shape_bytes():
     assert shape_bytes("f8e4m3fn[10,10]") == 100
     assert shape_bytes("token[]") == 0          # unknown dtype ignored
     assert shape_bytes("f32[]") == 4
+
+
+def test_compiled_bytes_counts_arguments_and_donation():
+    import jax
+    x = jnp.zeros((256, 128), jnp.float32)
+    f = lambda a: a * 2.0 + 1.0
+    plain = compiled_bytes(jax.jit(f).lower(x).compile())
+    donated = compiled_bytes(
+        jax.jit(f, donate_argnums=0).lower(x).compile())
+    for m in (plain, donated):
+        assert m["argument"] >= x.nbytes and m["output"] >= x.nbytes
+        assert m["peak"] == (m["argument"] + m["output"] + m["temp"]
+                             - m["alias"])
+    assert donated["alias"] >= plain["alias"]
 
 
 def test_collective_bytes_parser():
