@@ -34,6 +34,7 @@ from repro.core.topology import make_topology
 from repro.scenarios.attacks import tree_select  # noqa: F401 (re-export:
                                                  # async_defta/fedavg/tests
                                                  # import it from here)
+from repro.telemetry.trace import span
 
 __all__ = ["DeFTAState", "build_round", "build_round_fn", "evaluate",
            "global_model", "init_state", "local_train_fn",
@@ -70,10 +71,14 @@ def build_round(*args, **kwargs):
 def evaluate(task: Task, state: DeFTAState, test_x, test_y,
              malicious: np.ndarray):
     """Mean/std test accuracy across vanilla (non-malicious) workers."""
-    w = state.conf.shape[0]
-    accs = jax.vmap(lambda p: task.accuracy(
-        p, test_x, test_y, jnp.ones(test_x.shape[0])))(state.params)
-    accs = np.asarray(accs)[~malicious]
+    with span("evaluate"):
+        with span("evaluate.copy"):
+            tx, ty = jnp.asarray(test_x), jnp.asarray(test_y)
+        with span("evaluate.accuracy"):
+            accs = jax.vmap(lambda p: task.accuracy(
+                p, tx, ty, jnp.ones(tx.shape[0])))(state.params)
+        with span("evaluate.fetch"):
+            accs = np.asarray(accs)[~malicious]
     return float(accs.mean()), float(accs.std()), accs
 
 

@@ -1049,6 +1049,7 @@ def drive_epochs(rnd_fn, state, jdata, epochs: int, *, eval_every: int = 0,
     Returns ``(state, history)``.
     """
     from repro.telemetry.ledger import RunLedger
+    from repro.telemetry.trace import note_program, span
     led = ledger if ledger is not None else RunLedger()
     telemetry = getattr(rnd_fn, "telemetry", None)
     history = []
@@ -1080,7 +1081,8 @@ def drive_epochs(rnd_fn, state, jdata, epochs: int, *, eval_every: int = 0,
             flush(frames, e, 1, time.perf_counter() - t0)
             if eval_every and (e + 1) % eval_every == 0 \
                     and eval_fn is not None:
-                history.append(eval_fn(state, e + 1))
+                with span("eval", round=e + 1):
+                    history.append(eval_fn(state, e + 1))
     else:
         @functools.partial(jax.jit, static_argnames=("length",),
                            donate_argnums=(0,))
@@ -1100,15 +1102,23 @@ def drive_epochs(rnd_fn, state, jdata, epochs: int, *, eval_every: int = 0,
             else epochs
         while done < epochs:
             n = min(chunk, epochs - done)
-            t0 = time.perf_counter()
-            state, frames = run_chunk(state, jdata, jnp.int32(done),
-                                      length=n)
-            jax.block_until_ready(state)
-            flush(frames, done, n, time.perf_counter() - t0)
+            with span("chunk", round=done, length=n) as sp:
+                args = (state, jdata, jnp.int32(done))
+                programs = run_chunk._cache_size()
+                with span("dispatch"):
+                    state, frames = run_chunk(*args, length=n)
+                if run_chunk._cache_size() != programs:
+                    # a new program: keep its specs for the scope map
+                    note_program("run_chunk", run_chunk, args,
+                                 {"length": n}, stage_names(rnd_fn))
+                with span("wait"):
+                    jax.block_until_ready(state)
+            flush(frames, done, n, sp.seconds)
             done += n
             if eval_every and done % eval_every == 0 \
                     and eval_fn is not None:
-                history.append(eval_fn(state, done))
+                with span("eval", round=done):
+                    history.append(eval_fn(state, done))
 
     led.finish("epochs", epochs)
     if stats is not None:

@@ -10,6 +10,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.data.synthetic import make_lm_stream
+from repro.telemetry.trace import span
 
 
 class TokenBatcher:
@@ -20,11 +21,12 @@ class TokenBatcher:
         self.seed = seed
 
     def batch_at(self, step: int):
-        rng = np.random.default_rng((self.seed, step))
-        toks = make_lm_stream(self.batch, self.seq_len,
-                              min(self.vocab, 512), rng)
-        return {"tokens": toks.astype(np.int32),
-                "labels": toks.astype(np.int32)}
+        with span("batch", step=step):
+            rng = np.random.default_rng((self.seed, step))
+            toks = make_lm_stream(self.batch, self.seq_len,
+                                  min(self.vocab, 512), rng)
+            return {"tokens": toks.astype(np.int32),
+                    "labels": toks.astype(np.int32)}
 
     def __iter__(self):
         step = 0

@@ -465,7 +465,8 @@ def run_model(args) -> dict:
     """Train one model (or, with ``--fl``, one replica per pod that gossip
     every ``--gossip-every`` steps). Returns ``{"params", "losses" [steps,
     pods] (or [steps]), "step_s" [steps], "memory"}``; each step's seconds
-    end at its loss on the host, so they include that step's compiles.
+    are its ``step`` span (``telemetry.trace``), from the train-step call
+    to its loss on the host, so they include that step's compiles.
     ``memory`` maps each step program to its compiled footprint
     (``roofline.compiled_bytes``) with ``--memory-report``, else it is
     empty."""
@@ -483,6 +484,7 @@ def run_model(args) -> dict:
     from repro.models import model as model_mod
     from repro.optim import make_optimizer
     from repro.sharding import logical_rules
+    from repro.telemetry.trace import span
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -635,16 +637,19 @@ def run_model(args) -> dict:
                 b = batcher.batch_at(i)
                 batch = {k: jnp.asarray(v).reshape(
                     pods, args.batch // pods, -1) for k, v in b.items()}
-                t0 = time.time()
-                params, opt_state, step, losses = fl_step(
-                    params, opt_state, step, batch)
-                if (i + 1) % args.gossip_every == 0:
-                    pstate, params = gossip(pstate, params, losses,
-                                            gossip_start)
-                    if track_start:
-                        gossip_start = jax.tree.map(jnp.copy, params)
-                hist.append(np.asarray(losses, np.float64))
-                step_s.append(time.time() - t0)
+                with span("step", step=i) as sp:
+                    params, opt_state, step, losses = fl_step(
+                        params, opt_state, step, batch)
+                    if (i + 1) % args.gossip_every == 0:
+                        with span("gossip", step=i):
+                            pstate, params = gossip(pstate, params, losses,
+                                                    gossip_start)
+                            if track_start:
+                                gossip_start = jax.tree.map(jnp.copy,
+                                                            params)
+                    with span("loss_sync", step=i):
+                        hist.append(np.asarray(losses, np.float64))
+                step_s.append(sp.seconds)
                 print(f"step {i:4d} losses="
                       f"{[round(float(x), 4) for x in hist[-1]]} "
                       f"({step_s[-1]:.2f}s)"
@@ -662,11 +667,12 @@ def run_model(args) -> dict:
             for i in range(args.steps):
                 b = batcher.batch_at(i)
                 batch = {k: jnp.asarray(v) for k, v in b.items()}
-                t0 = time.time()
-                params, opt_state, step, loss = tstep(params, opt_state,
-                                                      step, batch)
-                hist.append(float(loss))
-                step_s.append(time.time() - t0)
+                with span("step", step=i) as sp:
+                    params, opt_state, step, loss = tstep(
+                        params, opt_state, step, batch)
+                    with span("loss_sync", step=i):
+                        hist.append(float(loss))
+                step_s.append(sp.seconds)
                 print(f"step {i:4d} loss={hist[-1]:.4f} "
                       f"({step_s[-1]:.2f}s)")
             if args.memory_report:

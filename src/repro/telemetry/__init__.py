@@ -8,6 +8,10 @@ stacked ys — no extra dispatches, and ``telemetry=None`` traces nothing
 Host side (``ledger``/``sinks``): ``RunLedger`` unifies the drivers'
 dispatch/wall-clock accounting and flushes probe frames at eval
 boundaries into a JSONL sink with a run manifest header.
+
+Host spans (``trace``): ``defta:`` profiler annotations where the host
+works, compile markers, and the map from a program's HLO instructions to
+its pipeline stages.
 """
 from repro.telemetry.spec import (
     MetricSpec,
