@@ -63,6 +63,67 @@ def mlp_task(input_dim: int, num_classes: int, hidden: int = 64) -> Task:
 # CNN (paper's MnistNet/CNNCifar class) on [H, W, C] images
 # ---------------------------------------------------------------------------
 
+def _pool_corners(x):
+    """The four stride-2 corners of every 2×2 window of an even-sized NHWC
+    array, in window order (0,0), (0,1), (1,0), (1,1)."""
+    n, h, w, c = x.shape
+    return [jax.lax.slice(x, (0, r, s, 0), (n, h, w, c), (1, 2, 2, 1))
+            for r in (0, 1) for s in (0, 1)]
+
+
+def _pool_max(corners):
+    a, b, c, d = corners
+    return jnp.maximum(jnp.maximum(a, b), jnp.maximum(c, d))
+
+
+@jax.custom_vjp
+def _max_pool_even(x):
+    return _pool_max(_pool_corners(x))
+
+
+def _max_pool_even_fwd(x):
+    corners = _pool_corners(x)
+    y = _pool_max(corners)
+    a, b, c, _ = corners
+    winner = jnp.where(a == y, 0, jnp.where(b == y, 1, jnp.where(c == y, 2, 3)))
+    return y, winner.astype(jnp.int8)
+
+
+def _max_pool_even_bwd(winner, g):
+    n, h2, w2, c = g.shape
+    up = (n, h2, 2, w2, 2, c)          # each pooled cell over its 2×2 window
+    corner = (2 * jax.lax.broadcasted_iota(jnp.int8, up, 2)
+              + jax.lax.broadcasted_iota(jnp.int8, up, 4))
+    dx = jnp.where(jnp.broadcast_to(winner[:, :, None, :, None], up) == corner,
+                   jnp.broadcast_to(g[:, :, None, :, None], up),
+                   jnp.zeros((), g.dtype))
+    return (dx.reshape(n, 2 * h2, 2 * w2, c),)
+
+
+_max_pool_even.defvjp(_max_pool_even_fwd, _max_pool_even_bwd)
+
+
+@jax.jit
+def max_pool_2x2(x):
+    """2×2, stride-2, VALID max-pool of an NHWC array, with the gradient of
+    ``jax.lax.reduce_window(x, -inf, max, (1, 2, 2, 1), (1, 2, 2, 1),
+    "VALID")`` computed elementwise instead of by select-and-scatter.
+
+    An odd height or width drops its last row or column, as VALID does, and
+    those get a zero gradient. Each output's gradient goes to the first
+    element of its window, in the order (0,0), (0,1), (1,0), (1,1), whose
+    value equals the maximum, as select-and-scatter routes it; every other
+    element gets zero. The forward saves that winner as an int8 index at
+    pooled resolution, and the backward compares it with each element's
+    position in its window. The function is jitted so that an eager caller,
+    such as the evaluation, runs the pool as one program instead of
+    materialising the four corners of a large activation one by one; inside
+    another jit it inlines.
+    """
+    h, w = x.shape[1] // 2 * 2, x.shape[2] // 2 * 2
+    return _max_pool_even(x[:, :h, :w])
+
+
 def cnn_task(image_hw: int, channels: int, num_classes: int,
              width: int = 16) -> Task:
     def init(key):
@@ -76,19 +137,20 @@ def cnn_task(image_hw: int, channels: int, num_classes: int,
         }
 
     def apply(p, x):
+        # ReLU then max-pool, written as pool then ReLU: relu(max) equals
+        # max(relu) and both send a window's gradient to its first maximum
+        # when that is positive and nowhere otherwise, so values and
+        # gradients are the same; this order applies the ReLU and its mask
+        # at pooled resolution.
         x = x.reshape(x.shape[0], image_hw, image_hw, channels)
         x = jax.lax.conv_general_dilated(
             x, p["c1"], (1, 1), "SAME",
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.relu(x)
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                  (1, 2, 2, 1), "VALID")
+        x = jax.nn.relu(max_pool_2x2(x))
         x = jax.lax.conv_general_dilated(
             x, p["c2"], (1, 1), "SAME",
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.relu(x)
-        x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                  (1, 2, 2, 1), "VALID")
+        x = jax.nn.relu(max_pool_2x2(x))
         x = x.reshape(x.shape[0], -1)
         return x @ p["w"] + p["b"]
 
