@@ -28,7 +28,43 @@ class MoEConfig:
     top_k: int
     num_shared_experts: int = 0      # always-on experts (DeepSeekMoE)
     d_expert: int = 0                # per-expert FFN hidden size
-    router_aux_weight: float = 0.01  # load-balance loss weight
+    router_aux_weight: float = 0.01  # load-balance loss weight (softmax:
+                                     # Switch loss; sigmoid: DeepSeek-V3's
+                                     # sequence-wise balance loss, alpha)
+    # "softmax": top-k of softmax probabilities (Switch aux loss);
+    # "sigmoid": DeepSeek-V3's noaux_tc router (arXiv:2412.19437 §2.1.2):
+    # top-k on sigmoid scores + a selection-only bias, gates from the
+    # unbiased scores normalised over the k and scaled by routed_scaling,
+    # the bias moved after each step by bias_update_rate * sign(mean load
+    # - load). Sigmoid layers dispatch dropless (models/moe.moe_share).
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    bias_update_rate: float = 0.0
+    # the experts this chip holds of each layer under expert parallelism:
+    # experts [held_start, held_start + num_held); 0 = all. The router
+    # keeps all num_experts outputs; the layer returns the held experts'
+    # part of the result (sigmoid router only).
+    num_held: int = 0
+    held_start: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): a compressed KV latent
+    with its own RMSNorm and a decoupled rotary key shared by all heads.
+    Queries are a plain projection (q_lora_rank null, as Moonlight)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -62,6 +98,7 @@ class ModelConfig:
     # FFN / block structure
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA
     attn_period: int = 1             # hybrid: 1 attention layer every N layers
                                      # (jamba: 8 -> layers i%8==attn_offset attn)
     attn_offset: int = 0
@@ -120,7 +157,15 @@ class ModelConfig:
             total += self.vocab_size * d                 # lm head
         for i in range(self.num_layers):
             kind = self.block_kind(i)
-            if kind in (ATTN_DENSE, ATTN_MOE):
+            if kind in (ATTN_DENSE, ATTN_MOE) and self.mla is not None:
+                a = self.mla
+                total += (d * n_q * a.qk_head_dim
+                          + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                          + a.kv_lora_rank
+                          + a.kv_lora_rank * n_q * (a.qk_nope_head_dim
+                                                    + a.v_head_dim)
+                          + n_q * a.v_head_dim * d)
+            elif kind in (ATTN_DENSE, ATTN_MOE):
                 attn = d * (n_q * hd) + 2 * d * (n_kv * hd) + (n_q * hd) * d
                 total += attn
             else:  # mamba block (matches models/ssm.init_ssm exactly)
@@ -133,10 +178,15 @@ class ModelConfig:
                     + conv_dim + 3 * nh + d_in
             if kind in (ATTN_MOE, MAMBA_MOE):
                 m = self.moe
-                n_e = m.top_k if active_only else m.num_experts
+                n_e = m.held
+                if active_only:         # experts a token reaches here
+                    n_e = m.top_k if m.held == m.num_experts \
+                        else m.top_k * m.held / m.num_experts
                 per_expert = 3 * d * m.d_expert
                 total += n_e * per_expert + m.num_shared_experts * per_expert
                 total += d * m.num_experts                # router
+                if m.router == "sigmoid":
+                    total += m.num_experts                # selection bias
             else:
                 mats = 2 if self.mlp_gelu else 3
                 total += mats * d * self.d_ff             # dense FFN
@@ -148,7 +198,7 @@ class ModelConfig:
             xattn = self.num_layers * (d * (n_q * hd) + 2 * d * (n_kv * hd)
                                        + (n_q * hd) * d + d)
             total += enc + xattn
-        return total
+        return int(round(total))
 
 
 # ---------------------------------------------------------------------------
@@ -364,12 +414,17 @@ def reduced(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256,
     if moe is not None:
         moe = dataclasses.replace(
             moe, num_experts=min(moe.num_experts, max_experts),
+            num_held=min(moe.num_held, max_experts), held_start=0,
             top_k=min(moe.top_k, 2),
             num_shared_experts=min(moe.num_shared_experts, 1),
             d_expert=min(moe.d_expert, d_model))
     ssm = cfg.ssm
     if ssm is not None:
         ssm = dataclasses.replace(ssm, d_state=16, head_dim=32, chunk_size=32)
+    mla = cfg.mla
+    if mla is not None:
+        mla = MLAConfig(kv_lora_rank=64, qk_nope_head_dim=32,
+                        qk_rope_head_dim=16, v_head_dim=32)
     # keep the hybrid interleave meaningful at 2 layers
     attn_period = min(cfg.attn_period, num_layers) if cfg.attn_period > 1 else 1
     return dataclasses.replace(
@@ -377,7 +432,7 @@ def reduced(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256,
         d_model=d_model, num_heads=n_heads, num_kv_heads=n_kv,
         d_ff=min(cfg.d_ff, 2 * d_model) or 2 * d_model,
         vocab_size=min(cfg.vocab_size, 1024), head_dim=hd,
-        moe=moe, ssm=ssm, attn_period=attn_period,
+        moe=moe, ssm=ssm, mla=mla, attn_period=attn_period,
         attn_offset=min(cfg.attn_offset, max(0, attn_period - 1)),
         num_encoder_layers=min(cfg.num_encoder_layers, 2),
         encoder_seq_len=min(cfg.encoder_seq_len, 64),

@@ -18,6 +18,7 @@ ARCH_IDS = (
     "mamba2-780m",
     "deepseek-moe-16b",
     "granite-3-2b",
+    "moonlight-16b-a3b",
     "paper-small",        # the paper's own scale (tiny transformer)
 )
 

@@ -338,7 +338,7 @@ def body_cost(cfg: ModelConfig, shape: ShapeConfig, mesh, rules, *,
             def f(pp, xx):
                 aux = jnp.zeros((), jnp.float32)
                 for pos, kind in enumerate(pattern):
-                    xx, aux = blocks_mod.block_apply(
+                    xx, aux, _ = blocks_mod.block_apply(
                         pp[str(pos)], cfg, kind, xx, positions, aux,
                         window=window, moe_strategy=moe_strategy)
                 return (xx.astype(jnp.float32).mean() + aux)
@@ -351,7 +351,7 @@ def body_cost(cfg: ModelConfig, shape: ShapeConfig, mesh, rules, *,
         def body(params, x, positions):
             aux = jnp.zeros((), jnp.float32)
             for pos, kind in enumerate(pattern):
-                x, aux = blocks_mod.block_apply(
+                x, aux, _ = blocks_mod.block_apply(
                     params[str(pos)], cfg, kind, x, positions, aux,
                     window=window, moe_strategy=moe_strategy)
             return x

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig, ModelConfig, ShapeConfig
 from repro.core.gossip import mix_pytree
+from repro.models import blocks
 from repro.models import model as model_mod
 from repro.optim import make_optimizer
 from repro.launch.sharding_rules import base_rules, sharding_tree, with_sharding
@@ -32,20 +33,26 @@ from repro.launch.sharding_rules import base_rules, sharding_tree, with_sharding
 # ---------------------------------------------------------------------------
 
 def build_train_step(cfg: ModelConfig, optimizer, *, moe_strategy="grouped",
-                     microbatches: int = 1, accum_dtype=jnp.float32):
+                     microbatches: int = 1, accum_dtype=jnp.float32,
+                     with_stats: bool = False):
     """fwd+bwd+update. ``microbatches>1`` scans grad accumulation over the
     leading batch dim (fp32 accumulators by default; ``accum_dtype=bf16``
-    is the §Perf memory lever for the 1T-param archs)."""
+    is the §Perf memory lever for the 1T-param archs).
+
+    Sigmoid-routed MoE layers (DeepSeek-V3) move their router biases after
+    the update by the step's expert loads. ``with_stats`` appends those
+    MoE stats (``blocks.stack_apply``'s tree: per layer ``load`` [E] and
+    ``dropped``) to the step's outputs."""
     def grads_of(params, batch):
         def lf(p):
             return model_mod.loss_fn(p, cfg, batch,
                                      moe_strategy=moe_strategy)
-        (loss, _), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        return loss, grads
+        (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params)
+        return loss, grads, metrics.get("moe", {})
 
     def train_step(params, opt_state, step, batch):
         if microbatches == 1:
-            loss, grads = grads_of(params, batch)
+            loss, grads, stats = grads_of(params, batch)
         else:
             mb_batch = jax.tree.map(
                 lambda x: x.reshape((microbatches,
@@ -53,43 +60,50 @@ def build_train_step(cfg: ModelConfig, optimizer, *, moe_strategy="grouped",
                                     x.shape[1:]), batch)
 
             def mb_step(acc, one_batch):
-                loss, g = grads_of(params, one_batch)
+                loss, g, st = grads_of(params, one_batch)
                 acc = jax.tree.map(
                     lambda a, gg: a + gg.astype(accum_dtype), acc, g)
-                return acc, loss
+                return acc, (loss, st)
 
             acc0 = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, accum_dtype), params)
-            grads, losses = jax.lax.scan(mb_step, acc0, mb_batch)
+            grads, (losses, stats) = jax.lax.scan(mb_step, acc0, mb_batch)
             grads = jax.tree.map(
                 lambda g, p: (g / microbatches).astype(p.dtype), grads,
                 params)
             loss = losses.mean()
+            stats = jax.tree.map(lambda a: a.sum(0), stats)
         params, opt_state = optimizer.update(params, grads, opt_state, step)
+        if stats:
+            params = blocks.update_router_biases(params, cfg, stats)
+        if with_stats:
+            return params, opt_state, step + 1, loss, stats
         return params, opt_state, step + 1, loss
     return train_step
 
 
 def build_fl_train_step(cfg: ModelConfig, optimizer, *,
                         moe_strategy="grouped", microbatches: int = 1,
-                        spmd_axis_name=None, accum_dtype=jnp.float32):
+                        spmd_axis_name=None, accum_dtype=jnp.float32,
+                        with_stats: bool = False):
     """vmapped-over-pods train step. params/opt_state have leading axis
     [npods, ...] sharded over the ``pod`` mesh axis; batch is
     [npods, per_pod_batch, ...]. ``spmd_axis_name='pod'`` tells vmap the
     batched dim lives on the pod mesh axis (required when the body contains
-    shard_map, e.g. expert-parallel MoE)."""
+    shard_map, e.g. expert-parallel MoE). ``with_stats`` appends each
+    pod's MoE stats (``build_train_step``), stacked [npods, ...]."""
     inner = build_train_step(cfg, optimizer, moe_strategy=moe_strategy,
                              microbatches=microbatches,
-                             accum_dtype=accum_dtype)
+                             accum_dtype=accum_dtype, with_stats=with_stats)
 
     def fl_step(stacked_params, stacked_opt, step, batch):
         def one(p, o, b):
-            p2, o2, _, loss = inner(p, o, step, b)
-            return p2, o2, loss
-        p2, o2, losses = jax.vmap(
+            p2, o2, _, *rest = inner(p, o, step, b)
+            return (p2, o2, *rest)
+        p2, o2, *rest = jax.vmap(
             one, spmd_axis_name=spmd_axis_name)(stacked_params, stacked_opt,
                                                 batch)
-        return p2, o2, step + 1, losses
+        return (p2, o2, step + 1, *rest)
     return fl_step
 
 

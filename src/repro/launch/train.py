@@ -466,7 +466,10 @@ def run_model(args) -> dict:
     every ``--gossip-every`` steps). Returns ``{"params", "losses" [steps,
     pods] (or [steps]), "step_s" [steps], "memory"}``; each step's seconds
     are its ``step`` span (``telemetry.trace``), from the train-step call
-    to its loss on the host, so they include that step's compiles.
+    to its loss on the host, so they include that step's compiles; the
+    next step's batch is made meanwhile, started as the step's span opens
+    (``TokenBatcher.prefetch``; the batch thread is done long before the
+    span closes, so the span's two clocks stay together).
     ``memory`` maps each step program to its compiled footprint
     (``roofline.compiled_bytes``) with ``--memory-report``, else it is
     empty."""
@@ -638,6 +641,8 @@ def run_model(args) -> dict:
                 batch = {k: jnp.asarray(v).reshape(
                     pods, args.batch // pods, -1) for k, v in b.items()}
                 with span("step", step=i) as sp:
+                    if i + 1 < args.steps:
+                        batcher.prefetch(i + 1)
                     params, opt_state, step, losses = fl_step(
                         params, opt_state, step, batch)
                     if (i + 1) % args.gossip_every == 0:
@@ -668,6 +673,8 @@ def run_model(args) -> dict:
                 b = batcher.batch_at(i)
                 batch = {k: jnp.asarray(v) for k, v in b.items()}
                 with span("step", step=i) as sp:
+                    if i + 1 < args.steps:
+                        batcher.prefetch(i + 1)
                     params, opt_state, step, loss = tstep(
                         params, opt_state, step, batch)
                     with span("loss_sync", step=i):

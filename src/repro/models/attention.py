@@ -51,7 +51,8 @@ def _project_qkv(params, cfg: ModelConfig, x, kv_x, positions, kv_positions,
 
 
 def _sdpa(q, k, v, mask, num_kv_heads):
-    """q: [B,Sq,Hq,hd] k,v: [B,Sk,Hkv,hd] mask: [B,1,Sq,Sk] or None."""
+    """q, k: [B,Sq|Sk,Hq|Hkv,hd]; v: [B,Sk,Hkv,dv] (dv may differ from hd,
+    as in MLA); mask: [B,1,Sq,Sk] or None. Returns [B,Sq,Hq,dv]."""
     b_, sq, hq, hd = q.shape
     hkv = k.shape[2]
     group = hq // hkv
@@ -62,7 +63,7 @@ def _sdpa(q, k, v, mask, num_kv_heads):
         scores = jnp.where(mask[:, :, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqs,bshk->bqhgk", probs, v)
-    return out.reshape(b_, sq, hq, hd)
+    return out.reshape(b_, sq, hq, v.shape[-1])
 
 
 BLOCKED_ATTN_THRESHOLD = 2048   # use the memory-linear path above this S
@@ -75,9 +76,10 @@ def blocked_attention_sdpa(q, k, v, *, causal: bool = True, window: int = 0,
     score matrix — this is what makes 4k-train/32k-prefill lowerable; the
     Pallas kernel is the TPU-native twin of this schedule.
 
-    q: [B,Sq,Hq,hd]; k,v: [B,Sk,Hkv,hd]. Returns [B,Sq,Hq,hd].
+    q, k: [B,Sq|Sk,Hq|Hkv,hd]; v: [B,Sk,Hkv,dv]. Returns [B,Sq,Hq,dv].
     """
     b_, sq, hq, hd = q.shape
+    dv = v.shape[-1]
     sk, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
     bq = min(block_q, sq)
@@ -90,51 +92,66 @@ def blocked_attention_sdpa(q, k, v, *, causal: bool = True, window: int = 0,
     nq, nk = qp.shape[1] // bq, kp.shape[1] // bk
     qp = qp.reshape(b_, nq, bq, hkv, g, hd)
     kp = kp.reshape(b_, nk, bk, hkv, hd)
-    vp = vp.reshape(b_, nk, bk, hkv, hd)
+    vp = vp.reshape(b_, nk, bk, hkv, dv)
     scale = 1.0 / (hd ** 0.5)
-
-    def kv_step(carry, inp):
-        acc, m, l, q_blk, q0 = carry
-        k_blk, v_blk, k0 = inp
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", q_blk, k_blk,
-                       preferred_element_type=jnp.float32) * scale
-        qpos = q0 + jnp.arange(bq)[:, None]
-        kpos = k0 + jnp.arange(bk)[None, :]
-        msk = kpos < sk                                     # kv padding
-        if causal:
-            msk &= kpos <= qpos
-        if window > 0:
-            msk &= kpos > qpos - window
-        s = jnp.where(msk[None, None, None], s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhgqk,bkhd->bhgqd", p, v_blk.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-        return (acc_new, m_new, l_new, q_blk, q0), None
-
-    kv_step = jax.checkpoint(kv_step, prevent_cse=False)
 
     def q_step(_, inp):
         q_blk, qi = inp
         q0 = qi * bq
-        acc0 = jnp.zeros((b_, hkv, g, bq, hd), jnp.float32)
+
+        def kv_step(carry, inp):
+            k_blk, v_blk, k0 = inp
+
+            def update(carry):
+                acc, m, l = carry
+                s = jnp.einsum("bqhgd,bkhd->bhgqk", q_blk, k_blk,
+                               preferred_element_type=jnp.float32) * scale
+                qpos = q0 + jnp.arange(bq)[:, None]
+                kpos = k0 + jnp.arange(bk)[None, :]
+                msk = kpos < sk                             # kv padding
+                if causal:
+                    msk &= kpos <= qpos
+                if window > 0:
+                    msk &= kpos > qpos - window
+                s = jnp.where(msk[None, None, None], s, -1e30)
+                m_new = jnp.maximum(m, s.max(axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                alpha = jnp.exp(m - m_new)
+                l_new = l * alpha + p.sum(axis=-1)
+                acc_new = acc * alpha[..., None] + jnp.einsum(
+                    "bhgqk,bkhd->bhgqd", p, v_blk.astype(jnp.float32),
+                    preferred_element_type=jnp.float32)
+                return acc_new, m_new, l_new
+
+            # a tile wholly above the diagonal (or before the window) adds
+            # nothing: skip its work
+            live = jnp.bool_(True)
+            if causal:
+                live &= k0 <= q0 + bq - 1
+            if window > 0:
+                live &= k0 + bk - 1 > q0 - window
+            return jax.lax.cond(live, update, lambda c: c, carry), None
+
+        kv_step = jax.checkpoint(kv_step, prevent_cse=False)
+        acc0 = jnp.zeros((b_, hkv, g, bq, dv), jnp.float32)
         m0 = jnp.full((b_, hkv, g, bq), -1e30, jnp.float32)
         l0 = jnp.zeros((b_, hkv, g, bq), jnp.float32)
-        (acc, m, l, _, _), _ = jax.lax.scan(
-            kv_step, (acc0, m0, l0, q_blk, q0),
+        (acc, m, l), _ = jax.lax.scan(
+            kv_step, (acc0, m0, l0),
             (kp.swapaxes(0, 1), vp.swapaxes(0, 1),
              jnp.arange(nk) * bk))
         out = (acc / jnp.maximum(l, 1e-20)[..., None]).astype(q.dtype)
-        return None, out                                     # [b,hkv,g,bq,hd]
+        return None, out                                     # [b,hkv,g,bq,dv]
+
+    # only each query tile's inputs are kept for the backward pass; its
+    # kv sweep is recomputed there
+    q_step = jax.checkpoint(q_step, prevent_cse=False)
 
     _, outs = jax.lax.scan(q_step, None,
                            (qp.swapaxes(0, 1), jnp.arange(nq)))
     out = jnp.moveaxis(outs, 0, 3)                 # [b,hkv,g,nq,bq,hd]
-    out = out.reshape(b_, hkv, g, nq * bq, hd)[:, :, :, :sq]
-    out = jnp.moveaxis(out, 3, 1).reshape(b_, sq, hq, hd)
+    out = out.reshape(b_, hkv, g, nq * bq, dv)[:, :, :, :sq]
+    out = jnp.moveaxis(out, 3, 1).reshape(b_, sq, hq, dv)
     return out
 
 

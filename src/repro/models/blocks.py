@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.config import (ATTN_DENSE, ATTN_MOE, MAMBA, MAMBA_MOE, ModelConfig)
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (Builder, gelu_mlp, init_gelu_mlp, init_mlp,
@@ -57,7 +58,9 @@ def factor_schedule(schedule: Tuple[str, ...]):
 
 def init_block(b: Builder, cfg: ModelConfig, kind: str, cross: bool = False):
     b.ones("ln1", (cfg.d_model,), ("embed",))
-    if kind in (ATTN_DENSE, ATTN_MOE):
+    if kind in (ATTN_DENSE, ATTN_MOE) and cfg.mla is not None:
+        mla_mod.init_mla(b.sub("attn"), cfg)
+    elif kind in (ATTN_DENSE, ATTN_MOE):
         attn_mod.init_attention(b.sub("attn"), cfg)
     else:
         ssm_mod.init_ssm(b.sub("ssm"), cfg)
@@ -77,9 +80,13 @@ def init_block(b: Builder, cfg: ModelConfig, kind: str, cross: bool = False):
 
 def block_apply(params, cfg: ModelConfig, kind: str, x, positions, aux,
                 *, window: int = 0, enc_out=None, moe_strategy="grouped"):
-    """Training/prefill. x: [B,S,D] -> (x, aux)."""
+    """Training/prefill. x: [B,S,D] -> (x, aux, stats); ``stats`` is the
+    MoE layer's (``moe.moe_ffn_stats``), None for every other layer."""
+    stats = None
     h = rms_norm(x, params["ln1"], cfg.norm_eps)
-    if kind in (ATTN_DENSE, ATTN_MOE):
+    if kind in (ATTN_DENSE, ATTN_MOE) and cfg.mla is not None:
+        h = mla_mod.mla(params["attn"], cfg, h, positions)
+    elif kind in (ATTN_DENSE, ATTN_MOE):
         h = attn_mod.attention(params["attn"], cfg, h, positions,
                                window=window)
     else:
@@ -91,8 +98,8 @@ def block_apply(params, cfg: ModelConfig, kind: str, x, positions, aux,
         x = x + attn_mod.cross_attention(params["xattn"], cfg, h, enc_out)
     if kind in (ATTN_MOE, MAMBA_MOE):
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
-        h, moe_aux = moe_mod.moe_ffn(params["moe"], cfg, h,
-                                     strategy=moe_strategy)
+        h, moe_aux, stats = moe_mod.moe_ffn_stats(params["moe"], cfg, h,
+                                                  strategy=moe_strategy)
         aux = aux + moe_aux
         x = x + h
     elif cfg.d_ff > 0:
@@ -100,7 +107,7 @@ def block_apply(params, cfg: ModelConfig, kind: str, x, positions, aux,
         ffn = gelu_mlp if cfg.mlp_gelu else mlp
         x = x + ffn(params["mlp"], h)
     x = constrain(x, "batch", "act_seq", "embed")
-    return x, aux
+    return x, aux, stats
 
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
@@ -182,32 +189,72 @@ def init_stack(b: Builder, cfg: ModelConfig, cross: bool = False):
 
 def stack_apply(params, cfg: ModelConfig, x, positions, *, window: int = 0,
                 enc_out=None, moe_strategy="grouped"):
-    """Apply the whole layer stack. Returns (x, aux_loss)."""
+    """Apply the whole layer stack. Returns (x, aux_loss, stats): the MoE
+    layers' stats laid out as their parameters are ({"prefix" | "layers":
+    {i: stats}, "scan": {pos: stats stacked over repeats}}), empty where
+    no layer has any."""
     schedule = cfg.block_schedule()
     prefix_len, pattern, repeats = factor_schedule(schedule)
     aux = jnp.zeros((), jnp.float32)
+    stats = {}
+
+    def keep(group, key, st):
+        if st is not None:
+            stats.setdefault(group, {})[key] = st
+
     for i in range(prefix_len):
-        x, aux = block_apply(params["prefix"][str(i)], cfg, schedule[i], x,
-                             positions, aux, window=window, enc_out=enc_out,
-                             moe_strategy=moe_strategy)
+        def one(p, x, aux, kind=schedule[i]):
+            return block_apply(p, cfg, kind, x, positions, aux,
+                               window=window, enc_out=enc_out,
+                               moe_strategy=moe_strategy)
+        if cfg.remat:
+            one = jax.checkpoint(one, prevent_cse=False)
+        x, aux, st = one(params["prefix"][str(i)], x, aux)
+        keep("prefix", str(i), st)
     if cfg.scan_layers and repeats > 1:
         def body(carry, layer_params):
             xc, auxc = carry
+            ys = {}
             for pos, kind in enumerate(pattern):
-                xc, auxc = block_apply(layer_params[str(pos)], cfg, kind, xc,
-                                       positions, auxc, window=window,
-                                       enc_out=enc_out,
-                                       moe_strategy=moe_strategy)
-            return (xc, auxc), None
+                xc, auxc, st = block_apply(layer_params[str(pos)], cfg, kind,
+                                           xc, positions, auxc,
+                                           window=window, enc_out=enc_out,
+                                           moe_strategy=moe_strategy)
+                if st is not None:
+                    ys[str(pos)] = st
+            return (xc, auxc), (ys or None)
         if cfg.remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux), _ = jax.lax.scan(body, (x, aux), params["scan"])
+        (x, aux), ys = jax.lax.scan(body, (x, aux), params["scan"])
+        for pos, st in (ys or {}).items():
+            keep("scan", pos, st)
     else:
         for i in range(prefix_len, len(schedule)):
-            x, aux = block_apply(params["layers"][str(i)], cfg, schedule[i],
-                                 x, positions, aux, window=window,
-                                 enc_out=enc_out, moe_strategy=moe_strategy)
-    return x, aux
+            x, aux, st = block_apply(params["layers"][str(i)], cfg,
+                                     schedule[i], x, positions, aux,
+                                     window=window, enc_out=enc_out,
+                                     moe_strategy=moe_strategy)
+            keep("layers", str(i), st)
+    return x, aux, stats
+
+
+def update_router_biases(params, cfg: ModelConfig, stats):
+    """DeepSeek-V3's aux-loss-free balancing after a step: each sigmoid
+    router's bias moves by ``bias_update_rate · sign(mean load − load)``
+    over its experts (``stats`` as ``stack_apply`` returns them)."""
+    rate = cfg.moe.bias_update_rate
+    new = dict(params)
+    for group, layers in stats.items():
+        new[group] = dict(params[group])
+        for key, st in layers.items():
+            load = st["load"].astype(jnp.float32)
+            blk = dict(params[group][key])
+            moe_p = dict(blk["moe"])
+            moe_p["router_bias"] = moe_p["router_bias"] + rate * jnp.sign(
+                load.mean(-1, keepdims=True) - load)
+            blk["moe"] = moe_p
+            new[group][key] = blk
+    return new
 
 
 def init_stack_cache(cfg: ModelConfig, batch: int, seq_len: int,

@@ -40,10 +40,11 @@ class Builder:
         self.key, sub = jax.random.split(self.key)
         return sub
 
-    def _put(self, name, shape, axes, make):
+    def _put(self, name, shape, axes, make, dtype=None):
         assert len(shape) == len(axes), (name, shape, axes)
         if self.abstract:
-            self.params[name] = jax.ShapeDtypeStruct(tuple(shape), self.dtype)
+            self.params[name] = jax.ShapeDtypeStruct(tuple(shape),
+                                                     dtype or self.dtype)
         else:
             self.params[name] = make()
         self.axes[name] = tuple(axes)
@@ -54,9 +55,10 @@ class Builder:
             scale * jax.random.normal(self._next(), shape, jnp.float32)
         ).astype(self.dtype))
 
-    def zeros(self, name, shape, axes):
+    def zeros(self, name, shape, axes, dtype=None):
+        dtype = dtype or self.dtype
         return self._put(name, shape, axes,
-                         lambda: jnp.zeros(shape, self.dtype))
+                         lambda: jnp.zeros(shape, dtype), dtype)
 
     def ones(self, name, shape, axes):
         return self._put(name, shape, axes,

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models import blocks
-from repro.models.attention import bidirectional_attention
+from repro.models.attention import (BLOCKED_ATTN_THRESHOLD,
+                                    bidirectional_attention)
 from repro.models.layers import (Builder, embed, gelu_mlp, init_embed,
                                  init_gelu_mlp, rms_norm, sinusoidal_at,
                                  sinusoidal_positions, unembed)
@@ -121,14 +122,20 @@ def _embed_inputs(params, cfg: ModelConfig, batch):
     return x, positions, offset, enc_out
 
 
-def forward(params, cfg: ModelConfig, batch, *, moe_strategy="grouped"):
-    """Training/prefill forward. Returns (logits [B,S',V], aux_loss)."""
+def _hidden(params, cfg: ModelConfig, batch, moe_strategy):
+    """Final-norm hidden states [B,S',D], text offset, aux loss, MoE stats."""
     x, positions, offset, enc_out = _embed_inputs(params, cfg, batch)
     x = constrain(x, "batch", "act_seq", "embed")
-    x, aux = blocks.stack_apply(params, cfg, x, positions,
-                                window=cfg.sliding_window, enc_out=enc_out,
-                                moe_strategy=moe_strategy)
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    x, aux, stats = blocks.stack_apply(params, cfg, x, positions,
+                                       window=cfg.sliding_window,
+                                       enc_out=enc_out,
+                                       moe_strategy=moe_strategy)
+    return rms_norm(x, params["ln_f"], cfg.norm_eps), offset, aux, stats
+
+
+def forward(params, cfg: ModelConfig, batch, *, moe_strategy="grouped"):
+    """Training/prefill forward. Returns (logits [B,S',V], aux_loss)."""
+    x, offset, aux, _ = _hidden(params, cfg, batch, moe_strategy)
     logits = unembed(params, x, cfg.tie_embeddings)
     logits = constrain(logits, "batch", "act_seq", "vocab")
     if offset:
@@ -136,19 +143,60 @@ def forward(params, cfg: ModelConfig, batch, *, moe_strategy="grouped"):
     return logits, aux
 
 
+LOSS_CHUNK = 1024   # above BLOCKED_ATTN_THRESHOLD positions the loss runs
+                    # over chunks of this many, never holding [B, S, V]
+
+
+def _chunked_ce(params, cfg: ModelConfig, x, labels):
+    """Next-token cross-entropy over chunks of LOSS_CHUNK positions, each
+    chunk's logits recomputed in the backward pass. x: [B,S,D]."""
+    b_, s, d = x.shape
+    n = s // LOSS_CHUNK
+    targets = jnp.concatenate(
+        [labels[:, 1:], jnp.full((b_, 1), -1, labels.dtype)], axis=1)
+    xs = x.reshape(b_, n, LOSS_CHUNK, d).swapaxes(0, 1)
+    ts = targets.reshape(b_, n, LOSS_CHUNK).swapaxes(0, 1)
+
+    @jax.checkpoint
+    def chunk(carry, inp):
+        xc, tc = inp
+        logits = unembed(params, xc, cfg.tie_embeddings).astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, jnp.maximum(tc, 0)[..., None],
+                                 axis=-1)[..., 0]
+        mask = (tc >= 0).astype(jnp.float32)
+        return (carry[0] + (ll * mask).sum(), carry[1] + mask.sum()), None
+
+    zero = jnp.zeros((), jnp.float32)
+    (ll, count), _ = jax.lax.scan(chunk, (zero, zero), (xs, ts))
+    return -ll / jnp.maximum(count, 1.0)
+
+
 def loss_fn(params, cfg: ModelConfig, batch, *, moe_strategy="grouped"):
-    """Next-token cross-entropy (+ MoE aux). Returns (loss, metrics)."""
-    logits, aux = forward(params, cfg, batch, moe_strategy=moe_strategy)
+    """Next-token cross-entropy (+ MoE aux). Returns (loss, metrics);
+    ``metrics["moe"]`` holds the MoE layers' stats where they have any."""
+    x, offset, aux, stats = _hidden(params, cfg, batch, moe_strategy)
     labels = batch["labels"]
-    logits = logits[:, :-1].astype(jnp.float32)
-    targets = labels[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    mask = (targets >= 0).astype(jnp.float32)
-    ce = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    if (not offset and x.shape[1] > BLOCKED_ATTN_THRESHOLD
+            and x.shape[1] % LOSS_CHUNK == 0):
+        ce = _chunked_ce(params, cfg, x, labels)
+    else:
+        logits = unembed(params, x, cfg.tie_embeddings)
+        logits = constrain(logits, "batch", "act_seq", "vocab")
+        if offset:
+            logits = logits[:, offset:]
+        logits = logits[:, :-1].astype(jnp.float32)
+        targets = labels[:, 1:]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        mask = (targets >= 0).astype(jnp.float32)
+        ce = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     aux_w = cfg.moe.router_aux_weight if cfg.moe is not None else 0.0
     loss = ce + aux_w * aux
-    return loss, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": aux}
+    if stats:
+        metrics["moe"] = stats
+    return loss, metrics
 
 
 # ---------------------------------------------------------------------------

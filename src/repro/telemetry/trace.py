@@ -125,10 +125,25 @@ _OPERAND = re.compile(r"%([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
+_WRAPPED = re.compile(r"^[\w.]+\((.*)\)$")
+
+
+def _unwrap(component: str) -> str:
+    """A path component with JAX's transform wrappers taken off:
+    ``transpose(jvp(mla))`` -> ``mla`` (a scope inside a differentiated or
+    vmapped function is named so)."""
+    m = _WRAPPED.match(component)
+    while m:
+        component = m.group(1)
+        m = _WRAPPED.match(component)
+    return component
+
+
 def hlo_scopes(text: str, stages: Sequence[str]) -> Dict[str, Optional[str]]:
     """``{instruction: stage}`` for every instruction of an HLO module's
     text. An instruction's stage is the first component of its
-    ``op_name`` path that is one of ``stages``; one whose path names none
+    ``op_name`` path that is one of ``stages`` (transform wrappers such as
+    ``transpose(jvp(...))`` taken off); one whose path names none
     (the scan's own counter and slicing) maps to None. One with no
     ``op_name`` at all (a layout copy, a tuple, a loop parameter) takes
     the stage of its operands, else of its users, when they agree on
@@ -148,7 +163,8 @@ def hlo_scopes(text: str, stages: Sequence[str]) -> Dict[str, Optional[str]]:
             orphans.append(instr)
             out[instr] = None
         else:
-            out[instr] = next((p for p in op.group(1).split("/")
+            out[instr] = next((p for p in map(_unwrap,
+                                              op.group(1).split("/"))
                                if p in stages), None)
     users: Dict[str, list] = {}
     for instr, ops in operands.items():

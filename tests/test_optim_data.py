@@ -145,3 +145,32 @@ def test_lm_stream_learnable_structure():
             big[a, b] += 1
     rowp = big / np.maximum(big.sum(1, keepdims=True), 1)
     assert (rowp.max(1) > 0.3).mean() > 0.5
+
+
+def test_token_batcher_prefetch_gives_the_same_batches():
+    """A prefetched batch is the one ``batch_at`` makes directly, made on
+    another thread; a step other than the prefetched one is made in place
+    and leaves the prefetched one waiting."""
+    import threading
+
+    from repro.data import loader
+
+    direct = loader.TokenBatcher(64, 12, 3, seed=5)
+    ahead = loader.TokenBatcher(64, 12, 3, seed=5)
+    made_on = {}
+    make = ahead._make
+    ahead._make = lambda s: (made_on.setdefault(s, threading.get_ident()),
+                             make(s))[1]
+    got = {}
+    for step in range(4):
+        got[step] = ahead.batch_at(step)
+        ahead.prefetch(step + 1)
+    got[9] = ahead.batch_at(9)
+    got[4] = ahead.batch_at(4)
+    for step, batch in got.items():
+        want = direct.batch_at(step)
+        for k in ("tokens", "labels"):
+            np.testing.assert_array_equal(batch[k], want[k])
+    main = threading.get_ident()
+    assert [made_on[s] == main for s in (0, 1, 2, 3, 4, 9)] == [
+        True, False, False, False, False, True]

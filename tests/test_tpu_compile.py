@@ -11,6 +11,7 @@ or collection time): only one process at a time may load the TPU library,
 and under pytest-xdist every worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -171,3 +172,71 @@ def test_qwen3_two_pod_int8_gossip_round_fits_v5e(one_chip):
     resident = _nbytes(stack(jax.eval_shape(opt.init, params)))
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + resident
     assert used < V5E_HBM_BYTES, used / 1e9
+
+
+_INSTR = r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = "
+
+
+def test_qwen3_pod_step_compiles_to_the_same_program(one_chip):
+    """qwen3-0.6b's two-pod train step at the benchmark cell's shape (3
+    rows of 1024 a pod) lowers and compiles to as many HLO instructions,
+    with as many temporary bytes, as before the latent-attention and
+    sigmoid-router changes to the shared model code (counted on the tree
+    before them: 2,380 lowered, 4,437 compiled, 8,101,410,816 bytes)."""
+    import re
+
+    from repro.launch.steps import build_fl_train_step
+    from repro.optim import make_optimizer
+
+    cfg, params, stack = _qwen3_pods(one_chip)
+    opt = make_optimizer("adafactor", 1e-3)
+    toks = _sds((PODS, 3, 1024), jnp.int32, one_chip)
+    lowered = jax.jit(build_fl_train_step(cfg, opt),
+                      donate_argnums=(0, 1)).lower(
+        stack(params), stack(jax.eval_shape(opt.init, params)),
+        _sds((), jnp.int32, one_chip), {"tokens": toks, "labels": toks})
+    count = lambda text: len(re.findall(_INSTR, text, re.M))
+    assert count(lowered.as_text(dialect="hlo")) == 2380
+    compiled = lowered.compile()
+    assert count(compiled.as_text()) == 4437
+    assert compiled.memory_analysis().temp_size_in_bytes == 8_101_410_816
+
+
+def test_moonlight_two_pod_train_step_fits_v5e(one_chip):
+    """The moonlight.pods2.gossip4 cell's train step: Moonlight-16B-A3B's
+    block at its published widths, cut to 5 layers, 8 held experts and a
+    20,480 vocabulary, 2 pods of one 8,192-token row, adafactor, with the
+    MoE stats: arguments, temporaries and the int8 wire's EF residuals fit
+    the chip, and the held experts run as the TPU's grouped matmul
+    kernels (megablox ``gmm`` and ``tgmm``)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.launch.steps import build_fl_train_step
+    from repro.models import model as mm
+    from repro.optim import make_optimizer
+
+    base = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(base, num_layers=5, vocab_size=20_480,
+                              moe=dataclasses.replace(base.moe, num_held=8))
+    params = mm.abstract_params(cfg)
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) \
+        == 568_484_608
+    stack = lambda t: jax.tree.map(
+        lambda x: _sds((PODS,) + x.shape, x.dtype, one_chip), t)
+    opt = make_optimizer("adafactor", 1e-2)
+    toks = _sds((PODS, 1, 8192), jnp.int32, one_chip)
+    compiled = jax.jit(build_fl_train_step(cfg, opt, with_stats=True),
+                       donate_argnums=(0, 1)).lower(
+        stack(params), stack(jax.eval_shape(opt.init, params)),
+        _sds((), jnp.int32, one_chip),
+        {"tokens": toks, "labels": toks}).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + _ef_residual_bytes(params))
+    assert used < V5E_HBM_BYTES, used / 1e9
+    kernels = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"^\s*%?([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
+        compiled.as_text(), re.M)}
+    assert {"gmm", "tgmm"} <= kernels, kernels
+    assert "ragged-dot" not in compiled.as_text()
