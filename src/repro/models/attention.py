@@ -1,11 +1,17 @@
 """Attention: GQA/MQA/MHA with RoPE, qk_norm, bias, causal and sliding-window
 masks, KV-cache decode (ring buffer for sliding window), optional cross-attn.
 
-The jnp path here is the reference/compile path; the Pallas flash kernel in
-``repro.kernels`` is the TPU fast path (validated against this in tests).
+Up to ``BLOCKED_ATTN_THRESHOLD`` positions, training and prefill take
+``_sdpa`` (the whole [S, S] score matrix) on every platform. Above it they
+take ``blocked_attention_sdpa``: on one TPU device the Pallas splash flash
+kernel shipped with JAX (forward, ``dq`` and ``dkv`` kernels under one custom
+VJP); on the CPU, and under a mesh that the compiler partitions, a pure-jnp
+online-softmax schedule that is also the kernel's reference in the tests.
+``repro.kernels.flash_attention`` is on no model path.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -13,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models.layers import Builder, apply_rope, head_rms_norm
-from repro.sharding import constrain
+from repro.sharding import constrain, partitioned
 
 
 def init_attention(b: Builder, cfg: ModelConfig, cross: bool = False):
@@ -68,13 +74,109 @@ def _sdpa(q, k, v, mask, num_kv_heads):
 
 BLOCKED_ATTN_THRESHOLD = 2048   # use the memory-linear path above this S
 
+# The flash kernel's tiles: query rows a block, keys a block held in VMEM,
+# and keys a matmul within it. Chosen on a v5e from one microbenchmark of a
+# layer's forward and backward at 2 pods x 16 heads x 8,192 positions, q.k
+# 192 and v 128 (benchmarks/flash_tiles.py; the table is in PERF.md). The
+# backward is splash's separate dq and dkv kernels: its fused one sums a
+# bfloat16 dq partial per key block.
+FLASH_BLOCK_Q = 512
+FLASH_BLOCK_KV = 1024
+FLASH_BLOCK_KV_COMPUTE = 512
+
+
+def flash_tiling(s: int, block_q: int = FLASH_BLOCK_Q,
+                 block_kv: int = FLASH_BLOCK_KV,
+                 block_kv_compute: int = FLASH_BLOCK_KV_COMPUTE):
+    """splash ``BlockSizes`` for causal self-attention over ``s`` positions
+    (each block capped at ``s``; the dq and dkv kernels take the forward's
+    tiles), or None where ``s`` is not a multiple of every block, or a key
+    block is not a multiple of the 128 lanes: such shapes stay on the jnp
+    path."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+
+    bq, bkv = min(block_q, s), min(block_kv, s)
+    bkc = min(block_kv_compute, bkv)
+    if s % bq or s % bkv or bkv % bkc or bkc % 128:
+        return None
+    return BlockSizes(block_q=bq, block_kv=bkv, block_kv_compute=bkc,
+                      block_q_dkv=bq, block_kv_dkv=bkv,
+                      block_kv_dkv_compute=bkc, block_q_dq=bq,
+                      block_kv_dq=bkv)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(s: int, heads: int, tiles, interpret: bool):
+    """The causal splash kernel for ``heads`` query heads over ``s``
+    positions, built once per shape: its mask's block tables take about a
+    second of host time at 8,192 positions. They are made eagerly, as
+    constants, even when the first call is being traced."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    mask = sa.MultiHeadMask([sa.CausalMask((s, s))] * heads)
+    with jax.ensure_compile_time_eval():
+        return sa.make_splash_mha_single_device(mask, block_sizes=tiles,
+                                                interpret=interpret)
+
+
+def flash_attention_sdpa(q, k, v, *, tiles=None, interpret: bool = False):
+    """Causal self-attention as the Pallas splash flash kernel: scores,
+    softmax statistics and accumulators stay in VMEM; only q, k, v, the
+    output and a per-row log-sum-exp touch HBM. Its backward is the
+    kernel's own ``dq`` and ``dkv`` kernels, which compute the scores
+    again from q, k and the log-sum-exp.
+
+    q, k: [B,S,Hq|Hkv,hd]; v: [B,S,Hkv,dv] (dv may differ from hd; Hkv
+    divides Hq). q is scaled by 1/sqrt(hd) in f32, then cast back to its
+    dtype. Every matmul operand has the inputs' dtype: the scaled q, and
+    the probabilities p and score gradients ds that the backward kernels
+    round to it before the dv, dk and dq matmuls (``blocked_attention_jnp``
+    keeps p in f32). Scores, statistics and accumulators are f32.
+    ``tiles`` defaults to ``flash_tiling(S)``, which must not be None.
+    Returns [B,S,Hq,dv].
+    """
+    s, hq, hd = q.shape[1:]
+    tiles = flash_tiling(s) if tiles is None else tiles
+    kernel = _splash_kernel(s, hq, tiles, interpret)
+    q = (q.astype(jnp.float32) * (1.0 / hd ** 0.5)).astype(q.dtype)
+    heads_first = lambda x: x.swapaxes(1, 2)               # [B,H,S,d]
+    out = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
+    return out.swapaxes(1, 2)
+
 
 def blocked_attention_sdpa(q, k, v, *, causal: bool = True, window: int = 0,
                            block_q: int = 512, block_k: int = 1024):
+    """Attention that never materializes the [S, S] score matrix — what
+    makes 4k-train/32k-prefill lowerable. On the TPU, causal
+    self-attention (Sq = Sk) with no window, over a length that
+    ``flash_tiling`` tiles, outside a mesh that the compiler partitions
+    (``sharding.partitioned``), is the Pallas flash kernel
+    (``flash_attention_sdpa``). Everything else, and every shape on the
+    CPU, is ``blocked_attention_jnp`` with ``block_q`` / ``block_k``:
+    sliding windows (the kernel's local mask is not wired), cross-length
+    or non-causal calls, lengths that are not a multiple of the kernel's
+    tiles, and calls split over several devices by GSPMD (which cannot
+    partition a Mosaic kernel, and shards the jnp path by batch and
+    heads).
+
+    q, k: [B,Sq|Sk,Hq|Hkv,hd]; v: [B,Sk,Hkv,dv]. Returns [B,Sq,Hq,dv].
+    """
+    def jnp_path(q, k, v):
+        return blocked_attention_jnp(q, k, v, causal=causal, window=window,
+                                     block_q=block_q, block_k=block_k)
+
+    if (causal and window == 0 and q.shape[1] == k.shape[1]
+            and flash_tiling(q.shape[1]) is not None and not partitioned()):
+        return jax.lax.platform_dependent(q, k, v, tpu=flash_attention_sdpa,
+                                          default=jnp_path)
+    return jnp_path(q, k, v)
+
+
+def blocked_attention_jnp(q, k, v, *, causal: bool = True, window: int = 0,
+                          block_q: int = 512, block_k: int = 1024):
     """Flash-style online-softmax attention in pure jnp (lax.scan over query
-    and kv tiles + checkpointed inner body). Never materializes the [S, S]
-    score matrix — this is what makes 4k-train/32k-prefill lowerable; the
-    Pallas kernel is the TPU-native twin of this schedule.
+    and kv tiles + checkpointed inner body): ``blocked_attention_sdpa``'s
+    path off the TPU and for the shapes the kernel does not take.
 
     q, k: [B,Sq|Sk,Hq|Hkv,hd]; v: [B,Sk,Hkv,dv]. Returns [B,Sq,Hq,dv].
     """

@@ -208,7 +208,10 @@ def stack_apply(params, cfg: ModelConfig, x, positions, *, window: int = 0,
                                window=window, enc_out=enc_out,
                                moe_strategy=moe_strategy)
         if cfg.remat:
-            one = jax.checkpoint(one, prevent_cse=False)
+            # outside a loop XLA may merge the backward's recomputed
+            # forward with the first one (CSE) and keep the layer's
+            # activations through the whole backward: keep them apart
+            one = jax.checkpoint(one)
         x, aux, st = one(params["prefix"][str(i)], x, aux)
         keep("prefix", str(i), st)
     if cfg.scan_layers and repeats > 1:

@@ -11,9 +11,13 @@ Per token ``x`` (H heads; widths from ``cfg.mla``):
   softmax, ``Σ p·v`` → [H, v], then ``W_o``.
 
 Training computes this expanded form; the absorbed decode form (scores
-against the cached latent) is not implemented. Above
-``BLOCKED_ATTN_THRESHOLD`` positions the scores go through the blocked
-online-softmax path, as for GQA.
+against the cached latent) is not implemented. Up to
+``BLOCKED_ATTN_THRESHOLD`` positions the scores are ``_sdpa``'s whole
+[S, S] matrix. Above it they go through ``blocked_attention_sdpa``, as for
+GQA: on the TPU the Pallas splash flash kernel (q·k and v widths of their
+own, its backward the kernel's ``dq`` and ``dkv`` kernels), in scope
+``mla`` like the rest of this function; on the CPU the jnp online-softmax
+path.
 """
 from __future__ import annotations
 

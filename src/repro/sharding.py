@@ -105,6 +105,22 @@ def constrain(x, *logical_axes):
     return jax.lax.with_sharding_constraint(x, NamedSharding(s.mesh, spec))
 
 
+def partitioned() -> bool:
+    """Whether the code being traced is split over several devices by the
+    compiler (GSPMD), as far as the context shows: a mesh of more than one
+    device installed by ``logical_rules`` or ``jax.set_mesh``, unless a
+    ``shard_map`` has made every axis of it manual (each body then runs on
+    one device). A jit given multi-device shardings with no mesh in context
+    is not seen. Pallas TPU (Mosaic) kernels lower only where this is
+    False: the compiler cannot partition them."""
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and set(am.manual_axes) == set(am.axis_names):
+        return False
+    mesh = _ctx().mesh
+    return (mesh is not None and mesh.size > 1) or (not am.empty
+                                                    and am.size > 1)
+
+
 def named_sharding(logical_axes, shape=None) -> Optional[NamedSharding]:
     spec = resolve_spec(logical_axes, shape)
     if spec is None:

@@ -202,13 +202,12 @@ def test_qwen3_pod_step_compiles_to_the_same_program(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes == 8_101_410_816
 
 
-def test_moonlight_two_pod_train_step_fits_v5e(one_chip):
-    """The moonlight.pods2.gossip4 cell's train step: Moonlight-16B-A3B's
-    block at its published widths, cut to 5 layers, 8 held experts and a
-    20,480 vocabulary, 2 pods of one 8,192-token row, adafactor, with the
-    MoE stats: arguments, temporaries and the int8 wire's EF residuals fit
-    the chip, and the held experts run as the TPU's grouped matmul
-    kernels (megablox ``gmm`` and ``tgmm``)."""
+@pytest.fixture(scope="module")
+def moonlight_step(one_chip):
+    """The moonlight.pods2.gossip4 cell's train step compiled for a
+    described v5e: Moonlight-16B-A3B's block at its published widths, cut
+    to 5 layers, 8 held experts and a 20,480 vocabulary, 2 pods of one
+    8,192-token row, adafactor, with the MoE stats. (compiled, params)."""
     import dataclasses
 
     from repro.configs import get_config
@@ -231,12 +230,96 @@ def test_moonlight_two_pod_train_step_fits_v5e(one_chip):
         stack(params), stack(jax.eval_shape(opt.init, params)),
         _sds((), jnp.int32, one_chip),
         {"tokens": toks, "labels": toks}).compile()
+    return compiled, params
+
+
+def _kernel_calls(text):
+    """Names of the Pallas custom calls of a compiled module."""
+    return re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.-]+) = [^\n]*custom-call\([^\n]*"
+        r"tpu_custom_call", text, re.M)
+
+
+def test_moonlight_two_pod_train_step_fits_v5e(moonlight_step):
+    """The Moonlight cell's train step: arguments, temporaries (8.16 GB;
+    9.07 GB while latent attention took the jnp blocked path) and the int8
+    wire's EF residuals fit the chip, and the held experts run as the
+    TPU's grouped matmul kernels (megablox ``gmm`` and ``tgmm``)."""
+    compiled, params = moonlight_step
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + _ef_residual_bytes(params))
     assert used < V5E_HBM_BYTES, used / 1e9
-    kernels = {re.sub(r"\.\d+$", "", n) for n in re.findall(
-        r"^\s*%?([\w.-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
-        compiled.as_text(), re.M)}
+    kernels = {re.sub(r"\.\d+$", "", n)
+               for n in _kernel_calls(compiled.as_text())}
     assert {"gmm", "tgmm"} <= kernels, kernels
     assert "ragged-dot" not in compiled.as_text()
+
+
+def test_moonlight_attention_is_the_flash_kernel_in_scope_mla(
+        moonlight_step):
+    """In the Moonlight step latent attention at 8,192 positions runs as
+    the splash flash kernel (forward, ``dq`` and ``dkv`` custom calls), each
+    of which ``trace.hlo_scopes`` maps to ``mla`` (so ``mla_ms.moonlight``
+    counts it), and no ``while`` loop of the jnp blocked path is left in
+    that scope."""
+    from repro.telemetry.trace import hlo_scopes
+
+    text = moonlight_step[0].as_text()
+    scopes = hlo_scopes(text, ("mla", "moe_route", "moe_experts",
+                               "moe_shared"))
+    splash = [n for n in _kernel_calls(text) if n.startswith("splash_mha")]
+    kinds = {re.sub(r"_(no_)?residuals\.\d+$", "", n) for n in splash}
+    assert kinds == {"splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"}, \
+        kinds
+    assert {scopes[n] for n in splash} == {"mla"}, \
+        {n: scopes[n] for n in splash}
+    loops = re.findall(r"^\s*(?:ROOT\s+)?%?([\w.-]+) = [^\n]* while\(",
+                       text, re.M)
+    assert loops and not [n for n in loops if scopes[n] == "mla"]
+
+
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_long_attention_on_a_v5e_mesh_keeps_the_jnp_path(topo, kind):
+    """``attention()`` at qwen3-0.6b's widths and ``mla()`` at Moonlight's,
+    4,096 positions, forward and backward. On one chip they compile to the
+    splash kernel. On a 2x2 v5e mesh under ``base_rules`` (batch over
+    ``data``, heads over ``model``) the compiler partitions the program and
+    cannot partition a Mosaic kernel, so they take the jnp blocked path,
+    which compiles there."""
+    from repro.configs import get_config
+    from repro.launch.mesh import auto_mesh
+    from repro.launch.sharding_rules import base_rules
+    from repro.models import attention, mla
+    from repro.models.layers import Builder
+    from repro.sharding import logical_rules, named_sharding, partitioned
+
+    cfg = get_config("qwen3-0.6b" if kind == "gqa" else "moonlight-16b-a3b")
+    init, apply = ((attention.init_attention, attention.attention)
+                   if kind == "gqa" else (mla.init_mla, mla.mla))
+    b = Builder(jax.random.PRNGKey(0), jnp.dtype(cfg.dtype), abstract=True)
+    init(b, cfg)
+    rows, seq = 2, 4096
+    x_shape, pos_shape = (rows, seq, cfg.d_model), (rows, seq)
+
+    def loss(p, x, pos):
+        return jnp.sum(apply(p, cfg, x, pos).astype(jnp.float32))
+
+    def compiled_text(place):
+        params = {n: _sds(a.shape, a.dtype, place(b.axes[n], a.shape))
+                  for n, a in b.params.items()}
+        x = _sds(x_shape, jnp.dtype(cfg.dtype),
+                 place(("batch", "act_seq", "embed"), x_shape))
+        pos = _sds(pos_shape, jnp.int32, place(("batch", "seq"), pos_shape))
+        return jax.jit(jax.grad(loss)).lower(params, x, pos).compile() \
+            .as_text()
+
+    one = SingleDeviceSharding(topo.devices[0])
+    assert not partitioned()
+    assert _kernel_calls(compiled_text(lambda axes, shape: one))
+    mesh = auto_mesh((2, 2), ("data", "model"), devices=topo.devices)
+    with mesh, logical_rules(mesh, base_rules(multi_pod=False)):
+        assert partitioned()
+        text = compiled_text(named_sharding)
+    assert not _kernel_calls(text)
+    assert " while(" in text
