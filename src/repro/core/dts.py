@@ -506,21 +506,23 @@ def fused_trust_signal(dts_signal: str, loss_trust, geom, damaged,
 
 def geom_confidence_update(dts_signal: str, lam: float, conf, sampled, P,
                            loss_trust, damaged, deltas, mask, weights,
-                           sketch=None, lam_corr: float = 0.0):
-    """The geometric/correlation trust_update branch, shared verbatim by
-    the sync/async round and the pod round (the selections differ only in
-    which deltas, mask and sketch history they pass): score the deltas
-    (geometry) and/or the sketch history (cross-round correlation), fuse
-    with the loss channel per ``dts_signal``, and apply Algorithm 3's
-    masked update ``c ← c − m ∘ p · signal``. ``sketch`` is the
-    ALREADY-ROTATED [W, R, S] ring buffer (this round's sketch included),
-    required for the "corr"/"all" variants."""
+                           sketch=None, lam_corr: float = 0.0, corr=None):
+    """The confidence update of every round (dense, cross-device and pod):
+    score the deltas (geometry) and/or the sketch history (cross-round
+    correlation) over the live ``mask``, fuse with the loss channel per
+    ``dts_signal``, and apply Algorithm 3's masked update
+    ``c ← c − m ∘ p · signal``. ``"loss"`` scores nothing and is line 12
+    bit-exactly. ``sketch`` is the ALREADY-ROTATED [W, R, S] ring buffer
+    (this round's sketch included) that ``colluder_scores`` reads for the
+    "corr"/"all" variants, unless the caller passes the [W, W]
+    correlation scores it computed itself as ``corr`` (the cross-device
+    round's stamp-aligned ``correlation_suspicion``)."""
     gs = (geom_scores(deltas, mask, weights=weights)
           if dts_signal in ("geom", "both", "all") else None)
-    cs = (colluder_scores(sketch, mask, weights=weights)
-          if dts_signal in ("corr", "all") else None)
+    if corr is None and dts_signal in ("corr", "all"):
+        corr = colluder_scores(sketch, mask, weights=weights)
     signal = fused_trust_signal(dts_signal, loss_trust, gs, damaged, lam,
-                                corr=cs, lam_corr=lam_corr)
+                                corr=corr, lam_corr=lam_corr)
     return conf - sampled * P * signal
 
 

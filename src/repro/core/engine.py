@@ -1,64 +1,58 @@
-"""Unified round-program engine: ONE composable superstep pipeline behind
-DeFTA, async DeFTA, FedAvg, and the multi-pod ppermute path.
+"""The round-program engine: one stage pipeline behind sync and async
+DeFTA, the cross-device cohort round, the multi-pod gossip round and
+FedAvg.
 
-The DFL surveys (Gabrielli et al. 2023; Hallaji et al. 2024) frame a
-decentralized-FL round as a pipeline of interchangeable stages. This module
-makes that decomposition executable: a *round program* is an ordered tuple
-of named stages over a mutable round context::
+A decentralized-FL round is a pipeline of interchangeable stages
+(Gabrielli et al. 2023; Hallaji et al. 2024). A *round program* is an
+ordered tuple of named stages over a mutable round context, each run
+under its own ``jax.named_scope``. The stages from ``split_keys`` to
+``trust_update`` exist once, in ``block_stages``, and run on an n-row
+``DeFTAState`` block::
 
-    split_keys -> scenario_view -> peer_sample -> transport (mix/wire/EF)
-                -> damage_check -> local_train -> attack_inject
-                -> trust_update -> finalize/merge
+    split_keys -> peer_sample -> transport -> damage_check -> local_train
+               -> [dp_noise] -> attack_inject -> trust_update
 
-Each execution mode is a *stage selection* over this pipeline:
+Each execution mode is a selection around that block:
 
-* sync DeFTA (``core.defta``)    — the full list; static finalize without a
-  scenario, churn/straggler merge with one.
-* async DeFTA (``core.async_defta``) — the same round wrapped in a
-  fire-gated tick (``build_fire_gated_tick``): speed-sampled workers merge
-  the new state, the rest freeze.
-* FedAvg (``core.fedavg``)       — star topology: ``transport`` degrades to
-  a server broadcast going down and a size-weighted mean coming back up;
+* sync DeFTA (``build_defta_round``, driven by ``core.defta``): the block
+  over the [W] state, with ``scenario_view`` after ``split_keys`` and
+  ``finalize`` (static topology) or ``fire_merge`` (a scenario) last.
+* async DeFTA (``core.async_defta``): the sync round inside a fire-gated
+  tick (``build_fire_gated_tick``): speed-sampled workers merge the new
+  state, the rest freeze.
+* cross-device (``build_cross_device_round``, ``core.cross_device``):
+  ``participation`` gathers the round's k-member cohort out of the
+  enrolled population into a k-row block, the block runs on it, and
+  ``scatter_merge`` writes the firing rows back.
+* multi-pod (``build_pod_round``, ``launch.train --fl``): its own key
+  split, time machine, attack injection and finalize around the same
+  ``scenario_view``, ``peer_sample``, ``mix`` and ``confidence_update``;
+  local training happens outside, in ``build_fl_train_step``.
+* FedAvg (``build_fedavg_round``, ``core.fedavg``): a star topology —
+  server broadcast, local training, size-weighted mean, server optimizer;
   no peer sampling, no DTS, no time machine.
-* multi-pod (``launch.train --fl``) — ``build_pod_round``: the same
-  scenario/sample/transport/trust stages over the pod axis, with the
-  ``ppermute`` transport shipping the encoded wire payload on the
-  offset-skipping ring (local training happens outside, in
-  ``build_fl_train_step``). With ``cfg.time_machine`` + a ``self_eval``
-  callable the pod path gains the damage check too: a held-out
-  self-evaluation between gossip rounds guards what a pod adopts.
 
-The ``trust_update`` stage is itself a selection
-(``DeFTAConfig.dts_signal``): the paper's loss-delta signal (``"loss"``,
-bit-exact), the update-geometry signal of ``core.dts.geom_scores``
-(``"geom"``), the cross-round collusion-correlation signal of
-``core.dts.colluder_scores`` (``"corr"`` — DTS v3, scored over the
-[W, R, S] sign-sketch ring buffer the state carries), or their fusions
-(``"both"`` = loss+geom, ``"all"`` = loss+geom+corr) — one stage variant
-shared by every mode; see docs/ARCHITECTURE.md for the full stage
-contract. The sketch history is plain carried state (``DeFTAState.sketch``
-/ ``PodState.sketch``): it rotates inside ``trust_update`` and merges
-through finalize/fire/tick like every other buffer, so the correlation
-signal rides the scan supersteps with zero extra dispatches.
+Every variant is selected at build time from what the builder knows (the
+scenario, the wire, ``cfg.dts_signal``, the world) and traces no op and
+no PRNG split into a round that does not need it: that is what keeps
+``tests/golden_engine.json`` bit-identical. The confidence update
+(``confidence_update`` over ``dts.geom_confidence_update``) is itself a
+selection: the paper's loss delta (``"loss"``), the update geometry
+(``"geom"``), the cross-round collusion correlation over the sign-sketch
+ring buffer the state carries (``"corr"``), or their fusions (``"both"``,
+``"all"``). docs/ARCHITECTURE.md gives every stage's reads and writes.
 
-Transports are a pluggable stage (``make_transport``): ``in_jit`` wraps the
-einsum/pallas/sparse/quant backends of ``core.gossip.mix_pytree``;
-``ppermute`` wraps ``mix_pytree_ppermute`` for cross-pod meshes. Both honor
-the full wire stack (fp32/bf16/int8 payloads, EF21 residuals, stochastic
-rounding where supported).
+Transports are a pluggable stage (``make_transport``): ``in_jit`` wraps
+the einsum/pallas/sparse/quant backends of ``core.gossip.mix_pytree``,
+``ppermute`` the cross-pod ring and ``sharded`` the worker-sharded mix;
+each carries the full wire stack (fp32/bf16/int8 payloads, EF21
+residuals, secure-aggregation pads).
 
-Drivers are shared too: ``drive_epochs`` is the chunked-``lax.scan``
-superstep driver with donated buffers and dispatch accounting (one XLA
-dispatch per eval chunk) used by ``run_defta`` AND ``run_fedavg``;
-``drive_ticks`` is the tick driver with the device-side
-``lax.while_loop`` early exit used by ``run_async_defta``. The triplicated
-scan/while_loop scaffolding the three engines used to carry now lives here
-once.
-
-Parity contract: the pipeline reproduces the pre-refactor engines
-bit-identically at fixed seed (tests/test_engine.py vs
-tests/golden_engine.json) — stages split the old round bodies, they do not
-reorder a single op or PRNG split.
+The drivers: ``drive_epochs`` runs rounds in chunked ``lax.scan``
+supersteps with donated buffers, one XLA dispatch per eval chunk
+(``run_defta``, ``run_fedavg``, ``run_cross_device``); ``drive_ticks``
+runs async ticks with a device-side ``lax.while_loop`` early exit
+(``run_async_defta``).
 """
 from __future__ import annotations
 
@@ -363,6 +357,29 @@ def stage_names(round_fn) -> Tuple[str, ...]:
     return tuple(n for n, _ in getattr(round_fn, "stages", ()))
 
 
+def round_program(stages, *, rows: int, telemetry=None, shard=None,
+                  **context):
+    """The scannable round(state, data, epoch=None) -> next state body
+    over ``stages``: the context starts from the state, data, epoch and
+    ``context``; the last stage writes ``next``, whose [rows, ...] leaves
+    are constrained to ``shard``'s worker rows. With ``telemetry`` the
+    round also returns its probe frame — the probes declared so far (a
+    wrapper such as the async tick declares its own later)."""
+    specs = telemetry.specs if telemetry is not None else None
+
+    def round(state, data, epoch=None):
+        c = {"state": state, "data": data, "epoch": epoch, **context}
+        run_pipeline(stages, c)
+        nxt = constrain_worker_rows(c["next"], shard, rows)
+        if telemetry is None:
+            return nxt
+        return nxt, telemetry.collect(c, specs)
+
+    round.stages = stages
+    round.telemetry = telemetry
+    return round
+
+
 def split_round_keys(key, stochastic: bool, dp_update: bool) -> dict:
     """The frozen per-round PRNG split layout, in one place: key,
     k_sample, k_train, k_noise — plus k_wire on the stochastic int8 wire
@@ -408,6 +425,339 @@ def apply_update_dp(cfg: DeFTAConfig, key, start, trained):
     return jax.tree.map(jnp.add, start, jax.tree.unflatten(tdef, noised))
 
 
+def scenario_view(c: dict, scenario, adj, t):
+    """The round's effective topology at scenario round ``t``: writes
+    eff_adj = (per-segment or static) ``adj`` ∧ link_ok ∧ alive on both
+    endpoints, and with a scenario alive, fire, att_on, att_kind and
+    att_scale — one device-side lookup into the compiled arrays."""
+    if scenario is None:
+        c["eff_adj"] = adj
+        return
+    from repro.scenarios.compile import epoch_view
+    view = epoch_view(scenario, t)
+    c["alive"], c["fire"], c["att_on"] = \
+        view["alive"], view["fire"], view["attack_on"]
+    c["att_kind"], c["att_scale"] = scenario.attack_kind, scenario.attack_scale
+    base = view["adj"] if scenario.adj_seg is not None else adj
+    c["eff_adj"] = base & view["link_ok"] \
+        & c["alive"][None, :] & c["alive"][:, None]
+
+
+def peer_sample(cfg: DeFTAConfig, c: dict, conf, *,
+                listen_all: bool = False):
+    """The DTS peer sample every round runs: reads eff_adj, k_sample;
+    writes theta [n, n] (DTS sampling weights from ``conf``, observed by
+    theta-aware attacks and reused as the geometric reference weights)
+    and sampled [n, n] (Gumbel top-k, ≤ num_sampled per row). Without DTS
+    theta is the uniform row-normalized adjacency; ``listen_all`` (the pod
+    round) then listens to every live peer instead of sampling."""
+    if cfg.use_dts:
+        theta = dts_mod.sample_weights(conf, c["eff_adj"], cfg.crelu_slope)
+    else:
+        theta = c["eff_adj"] / jnp.maximum(
+            c["eff_adj"].sum(1, keepdims=True), 1)
+        if listen_all:
+            c["theta"], c["sampled"] = theta, c["eff_adj"]
+            return
+    c["theta"] = theta
+    skeys = jax.random.split(c["k_sample"], theta.shape[0])
+    c["sampled"] = jax.vmap(
+        lambda k, t: dts_mod.sample_peers(k, t, cfg.num_sampled)
+    )(skeys, theta)
+
+
+def mix(cfg: DeFTAConfig, transport: Transport, c: dict, params, wire_err,
+        round_, *, robust: bool = False, col_w=None, k_min: int = 1):
+    """The mixing stage every round runs: reads sampled, eff_adj, sizes
+    (on a dynamic matrix), k_wire; writes P (the mixing matrix), agg (the
+    mixed ``params``) and wire_err (the advanced EF21 residuals). A robust
+    rule (trimmed_mean/median/krum) replaces the weighted mix, and P
+    degrades to the uniform bookkeeping weights the confidence update
+    needs. Otherwise P is the static matrix of the build-time column
+    weights ``col_w``, or the round's ``dynamic_mixing_matrix`` over the
+    live adjacency; with ``k_min > 1`` rows with fewer surviving sampled
+    peers degrade to the identity (the worker self-trains). ``round_``
+    keys the secagg pads."""
+    n = c["sampled"].shape[0]
+    mask = (c["sampled"] & c["eff_adj"]) | jnp.eye(n, dtype=bool)
+    if robust:
+        from repro.scenarios.robust_agg import robust_mix
+        c["agg"] = robust_mix(cfg.aggregation, mask, params,
+                              trim=cfg.robust_trim)
+        c["P"] = mask / mask.sum(axis=1, keepdims=True)
+        c["wire_err"] = wire_err
+        return
+    if col_w is not None:
+        P = mask * col_w[None, :]
+        P = P / P.sum(axis=1, keepdims=True)
+    else:
+        # per-round outdegree renormalization under the live adjacency
+        # (churn, link failures and dropouts change |D_j|/d_j)
+        P = dynamic_mixing_matrix(c["sampled"], c["eff_adj"], c["sizes"],
+                                  cfg.aggregation)
+        if k_min > 1:
+            eye = jnp.eye(n, dtype=bool)
+            npeers = (c["sampled"] & c["eff_adj"] & ~eye).sum(axis=1)
+            P = jnp.where((npeers >= k_min)[:, None], P, eye.astype(P.dtype))
+    c["P"] = P
+    if transport.use_ef:
+        if wire_err is None:
+            raise ValueError(
+                "cfg enables gossip error feedback on a lossy wire but the "
+                "state carries no residual buffers — build it with "
+                "wire_error=True")
+        c["agg"], c["wire_err"] = transport.mix(
+            P, params, residual=wire_err, key=c["k_wire"], round_=round_)
+    else:
+        c["agg"] = transport.mix(P, params, key=c["k_wire"], round_=round_)
+        c["wire_err"] = wire_err
+
+
+def confidence_update(cfg: DeFTAConfig, c: dict, conf, sketch, loss_trust,
+                      damaged, deltas, *, masked_geom: bool = False,
+                      stamp=None):
+    """The confidence update every round runs, ``c ← c − m ∘ p · signal``
+    (Algorithm 3 line 12): reads sampled, P, theta, eff_adj (+ fire, and
+    epoch with ``stamp``); writes conf and sketch (+ stamp).
+
+    The signal is the loss delta (dts_signal="loss", bit-exact), or with
+    the extra channels of ``resolve_dts_signal`` the centered
+    update-geometry scores, the cross-round collusion-correlation scores,
+    or their fusions — both observe ``deltas``, each peer's local-update
+    delta, over the live neighborhood (non-firing peers excluded: their
+    delta is never consumed). The sketch ring buffer rotates with this
+    round's sign-sketch. ``stamp`` (the cross-device round's [n, R]
+    global-round stamps) rotates with it, and the correlation is then the
+    stamp-aligned ``dts.stamped_correlation`` instead of the dense
+    ``dts.colluder_scores``. ``masked_geom`` (aggregate-only secagg): the
+    receiver never sees a per-peer delta, so the channels degrade to the
+    pooled aggregate-minus-own-contribution signal broadcast over its
+    sampled row, and the sketch (and stamps) pass through unrotated."""
+    channels = resolve_dts_signal(cfg)
+    c["sketch"] = sketch
+    if stamp is not None:
+        c["stamp"] = stamp
+    signal, gmask, corr = "loss", None, None
+    if channels:
+        gmask = c["eff_adj"] & c["fire"][None, :] if "fire" in c \
+            else c["eff_adj"]
+    if channels and masked_geom:
+        loss_trust = loss_trust + cfg.dts_geom_weight \
+            * dts_mod.masked_geom_trust(deltas, c["P"], gmask)
+    elif channels:
+        signal = cfg.dts_signal
+        if "corr" in channels:
+            if sketch is None:
+                raise ValueError(
+                    f"dts_signal={cfg.dts_signal!r} needs the sketch ring "
+                    f"buffer — build the state with "
+                    f"sketch=sketch_shape(cfg)")
+            c["sketch"] = dts_mod.update_sketch(sketch, deltas,
+                                                seed=cfg.seed)
+            if stamp is not None:
+                c["stamp"] = jnp.concatenate(
+                    [stamp[:, 1:], jnp.full((stamp.shape[0], 1), c["epoch"],
+                                            jnp.int32)], axis=1)
+                cmat, valid = dts_mod.stamped_correlation(
+                    c["sketch"], c["stamp"], min_obs=cfg.dts_min_obs)
+                corr = dts_mod.correlation_suspicion(
+                    cmat, gmask, weights=c["theta"], valid=valid)
+    c["conf"] = dts_mod.geom_confidence_update(
+        signal, cfg.dts_geom_weight, conf, c["sampled"], c["P"], loss_trust,
+        damaged, deltas, gmask, c["theta"], sketch=c["sketch"],
+        lam_corr=cfg.dts_corr_weight, corr=corr)
+
+
+def block_stages(task: Task, cfg: DeFTAConfig, train: TrainConfig, n: int,
+                 transport: Transport, *, telemetry=None,
+                 robust: bool = False, col_w=None, k_min: int = 1,
+                 kinds_present=(), num_classes: int = 0, malicious=None,
+                 noise_scale: float = 200.0):
+    """The stages from ``split_keys`` to ``trust_update`` over an n-row
+    block: the dense round runs them on its [W] state, the cross-device
+    round on the [k] cohort its ``participation`` stage gathers. They read
+    ``state`` (a ``DeFTAState``), ``data``, ``sizes``, ``epoch``,
+    ``eff_adj``, and with attacks ``att_kind``/``att_scale``/``att_on``
+    (+ ``fire`` where rows may not fire, ``g_stamp`` on the cross-device
+    correlation channel) from the round context.
+
+    ``malicious`` (an [n] mask) selects the paper's aggregate + noise
+    attack of a round without a scenario; otherwise ``kinds_present``
+    compiles the scenario's attack kinds. A stage emits only the probes
+    its round's telemetry declared. Returns the ordered (name, fn)
+    tuple."""
+    from repro.scenarios import attacks as attacks_mod
+    from repro.scenarios.compile import ATTACK_CODE
+
+    ltrain = local_train_fn(task, train, cfg.local_epochs,
+                            dp_clip=cfg.dp_clip, dp_sigma=cfg.dp_sigma)
+    channels = resolve_dts_signal(cfg)
+    dp_update = uses_update_dp(cfg)
+    # masked_geom: the receiver of an aggregate-only secagg sees no
+    # per-peer update (see ``confidence_update``)
+    masked_geom = cfg.secagg is not None \
+        and cfg.secagg_mode == "masked_geom"
+    if "label_flip" in kinds_present and num_classes <= 0:
+        raise ValueError("label_flip attacks need num_classes > 0")
+
+    def on(name):
+        return telemetry is not None and name in telemetry
+
+    def stage_split_keys(c):
+        """reads state.key; writes key (next round), k_sample, k_train,
+        k_noise and — build-time gated — k_wire (stochastic int8 wire)
+        and k_dp (update-DP stage). The split layout is frozen
+        (``split_round_keys``): adding a split changes every downstream
+        draw."""
+        c.update(split_round_keys(c["state"].key, transport.stochastic,
+                                  dp_update))
+
+    def stage_peer_sample(c):
+        """reads eff_adj, state.conf, k_sample; writes theta and sampled
+        (``peer_sample``)."""
+        peer_sample(cfg, c, c["state"].conf)
+        if on("theta_in"):
+            telemetry.emit(c, "theta_in", c["theta"].mean(axis=0))
+
+    def stage_transport(c):
+        """reads sampled, eff_adj, sizes, state.params, state.wire_err,
+        k_wire, epoch; writes P, agg and wire_err (``mix``). This is the
+        pluggable stage: in_jit mix_pytree backends, the worker-sharded
+        ring, or a robust rule replacing the weighted mix."""
+        state = c["state"]
+        if telemetry is not None:
+            from repro.telemetry.spec import stacked_payload_bytes
+            live = (c["sampled"] & c["eff_adj"]
+                    & ~jnp.eye(n, dtype=bool)).sum()
+            telemetry.emit(c, "edges", live)
+            telemetry.emit(c, "wire_bytes", live.astype(jnp.float32) *
+                           stacked_payload_bytes(state.params,
+                                                 transport.wire))
+        mix(cfg, transport, c, state.params, state.wire_err,
+            0 if c["epoch"] is None else c["epoch"], robust=robust,
+            col_w=col_w, k_min=k_min)
+        if transport.use_ef and telemetry is not None:
+            telemetry.emit(c, "ef_norm", jnp.linalg.norm(
+                dts_mod.flatten_stacked(c["wire_err"]), axis=1))
+
+    def stage_damage_check(c):
+        """reads agg, data, att_kind, att_on, state.{backup,best_loss};
+        writes y_data (label-flip poisoned labels where active), loss_agg
+        (each row's self-evaluation of the aggregate), damaged and start
+        (the params local training departs from — the backup on damaged
+        rounds: the §3.3 time machine)."""
+        state, data = c["state"], c["data"]
+        y_data = data["y"]
+        if "label_flip" in kinds_present:
+            # data poisoning: label-flippers train (and self-evaluate) on
+            # y -> C-1-y; their protocol behaviour stays honest
+            lf = (c["att_kind"] == ATTACK_CODE["label_flip"]) & c["att_on"]
+            y_data = attacks_mod.flip_labels(y_data, lf, num_classes)
+        c["y_data"] = y_data
+        c["loss_agg"] = jax.vmap(task.loss)(c["agg"], data["x"], y_data,
+                                            data["mask"])
+        if cfg.time_machine:
+            c["damaged"] = dts_mod.is_damaged(c["loss_agg"],
+                                              state.best_loss)
+            c["start"] = tree_select(c["damaged"], state.backup, c["agg"])
+        else:
+            c["damaged"] = jnp.zeros_like(c["loss_agg"], bool)
+            c["start"] = c["agg"]
+        if on("loss_agg"):
+            telemetry.emit(c, "loss_agg", c["loss_agg"])
+        if on("damaged"):
+            telemetry.emit(c, "damaged", c["damaged"])
+
+    def stage_local_train(c):
+        """reads start, y_data, data, k_train; writes trained (post-SGD
+        stacked params) and train_loss — ``local_epochs`` minibatch epochs
+        per row, vmapped over the block."""
+        data = c["data"]
+        tkeys = jax.random.split(c["k_train"], n)
+        c["trained"], c["train_loss"] = jax.vmap(
+            lambda k, p, x, y, m: ltrain(k, p, x, y, m)
+        )(tkeys, c["start"], data["x"], c["y_data"], data["mask"])
+        if on("train_loss"):
+            telemetry.emit(c, "train_loss", c["train_loss"])
+
+    def stage_dp_noise(c):
+        """reads trained, start, k_dp; writes trained — per-round
+        update-DP (``apply_update_dp``): every row clips its local-update
+        delta and noises it BEFORE it becomes next round's send, so both
+        peers and the trust channels only ever observe the privatized
+        update. Build-time gated on ``uses_update_dp(cfg)`` (the default
+        σ=0 compiles this stage away entirely)."""
+        c["trained"] = apply_update_dp(cfg, c["k_dp"], c["start"],
+                                       c["trained"])
+
+    def stage_attack_inject(c):
+        """reads trained, agg, att_kind, att_scale, att_on, theta,
+        k_noise; writes trained (attacker rows replaced by their poisoned
+        sends — what peers consume NEXT round). theta feeds the adaptive
+        theta_aware gate."""
+        if malicious is None:
+            c["trained"] = attacks_mod.poison_sends(
+                c["k_noise"], kinds_present, c["att_kind"],
+                c["att_scale"], c["att_on"], c["agg"], c["trained"],
+                theta=c["theta"] if cfg.use_dts else None)
+        else:
+            # no scenario: the paper's aggregate+noise on ``malicious``
+            poisoned = attacks_mod.noise(
+                c["k_noise"], c["agg"], c["trained"],
+                jnp.full((n,), noise_scale, jnp.float32))
+            c["trained"] = tree_select(malicious, poisoned, c["trained"])
+
+    def stage_trust_update(c):
+        """reads loss_agg, damaged, trained, start, state.{conf, sketch,
+        best_loss, last_loss, backup} + what ``confidence_update`` reads;
+        writes conf, sketch (+ stamp), backup, best_loss, last_loss. The
+        scored delta is each row's LOCAL-UPDATE ``trained − start`` (the
+        step it applied on top of its adopted aggregate; post attack
+        injection, so the poison is exactly what gets scored)."""
+        state = c["state"]
+        loss_trust = jnp.where(c["damaged"], dts_mod.DAMAGE_PENALTY,
+                               c["loss_agg"] - state.last_loss)
+        deltas = None
+        if channels:
+            deltas = dts_mod.flatten_stacked(c["trained"]) \
+                - dts_mod.flatten_stacked(c["start"])
+        confidence_update(cfg, c, state.conf, state.sketch, loss_trust,
+                          c["damaged"], deltas, masked_geom=masked_geom,
+                          stamp=c.get("g_stamp"))
+        if telemetry is not None:
+            telemetry.emit(c, "loss_trust", loss_trust)
+            telemetry.emit(c, "conf_in", c["conf"].mean(axis=0))
+            # the scored observable: ‖trained − start‖ per row (on the
+            # channels path XLA CSEs this with the deltas above)
+            telemetry.emit(c, "update_norm", jnp.linalg.norm(
+                dts_mod.flatten_stacked(c["trained"])
+                - dts_mod.flatten_stacked(c["start"]), axis=1))
+
+        improved = (c["loss_agg"] < state.best_loss) & ~c["damaged"]
+        # the time machine's compensation step RATCHETS: a damaged round
+        # starts from the backup, so its trained result is train(backup) —
+        # clean by induction — and becomes the new backup. Without this a
+        # worker whose whole peer set is malicious (66%-regime reality)
+        # re-trains the same frozen backup forever and never progresses.
+        c["backup"] = tree_select(improved | c["damaged"], c["trained"],
+                                  state.backup)
+        c["best_loss"] = jnp.where(improved, c["loss_agg"],
+                                   state.best_loss)
+        c["last_loss"] = jnp.where(c["damaged"], state.last_loss,
+                                   c["loss_agg"])
+
+    return (
+        ("split_keys", stage_split_keys),
+        ("peer_sample", stage_peer_sample),
+        ("transport", stage_transport),
+        ("damage_check", stage_damage_check),
+        ("local_train", stage_local_train),
+    ) + ((("dp_noise", stage_dp_noise),) if dp_update else ()) + (
+        ("attack_inject", stage_attack_inject),
+        ("trust_update", stage_trust_update),
+    )
+
+
 def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
                       adj: np.ndarray, sizes: np.ndarray,
                       malicious: np.ndarray, *,
@@ -419,16 +769,17 @@ def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
     """The DeFTA round program: returns an UN-jitted
     round(state, data, epoch=None) -> state body — scannable, so drivers
     fuse many rounds into one XLA dispatch (and jittable as-is for
-    single-round use).
+    single-round use). Its stages are ``block_stages`` over the [W]
+    state, with ``scenario_view`` after ``split_keys`` and ``finalize`` (or
+    ``fire_merge`` with a scenario) at the end.
 
     ``scenario``: a ``repro.scenarios.CompiledScenario``. When given, the
     traced ``epoch`` index looks up that epoch's alive/link/fire/attack
     state (and, for time-varying topologies, the segment's regenerated
     adjacency) from the compiled device arrays — churn, partitions,
     stragglers and the whole attack zoo run INSIDE the scan body, no host
-    round-trips. Without it the body reproduces the legacy static-topology
-    round (with the paper's noise attack on ``malicious`` workers)
-    bit-for-bit.
+    round-trips. Without it the topology is static and the ``malicious``
+    workers send the paper's aggregate + noise.
 
     ``transport``: a ``Transport`` (default: ``make_transport`` over the
     in_jit ``gossip_backend``). ``num_classes`` is required when the
@@ -451,24 +802,18 @@ def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
     reductions (outdegrees, geometry scores, telemetry means) lower to
     collectives automatically. ``shard=None`` (default) changes nothing.
     """
+    from repro.scenarios.robust_agg import ROBUST_RULES
+
     w = adj.shape[0]
     adj_j = jnp.asarray(adj)
     sizes_j = jnp.asarray(np.asarray(sizes, np.float32))
-    adj_self = adj | np.eye(w, dtype=bool)
-    outdeg = jnp.asarray(adj_self.sum(axis=0).astype(np.float32))
-    malicious_j = jnp.asarray(malicious)
-    ltrain = local_train_fn(task, train, cfg.local_epochs,
-                            dp_clip=cfg.dp_clip, dp_sigma=cfg.dp_sigma)
-    channels = resolve_dts_signal(cfg)
-    corr = "corr" in channels
     max_staleness = int(cfg.max_staleness)
-
-    from repro.scenarios import attacks as attacks_mod
-    from repro.scenarios.compile import ATTACK_CODE, epoch_view
-    from repro.scenarios.robust_agg import ROBUST_RULES, robust_mix
-
     robust = cfg.aggregation in ROBUST_RULES
-    if not robust:
+    col_w = None
+    if scenario is None and not robust:
+        # static topology: the column weights are fixed at build time
+        outdeg = jnp.asarray((adj | np.eye(w, dtype=bool)).sum(axis=0)
+                             .astype(np.float32))
         if cfg.aggregation == "defta":
             col_w = sizes_j / outdeg
         elif cfg.aggregation == "defl":
@@ -476,12 +821,9 @@ def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
         else:  # uniform gossip
             col_w = jnp.ones_like(sizes_j)
 
-    if scenario is not None:
-        if scenario.num_workers != w:
-            raise ValueError(f"scenario compiled for W="
-                             f"{scenario.num_workers}, topology has {w}")
-        if "label_flip" in scenario.kinds_present and num_classes <= 0:
-            raise ValueError("label_flip scenario needs num_classes > 0")
+    if scenario is not None and scenario.num_workers != w:
+        raise ValueError(f"scenario compiled for W={scenario.num_workers}, "
+                         f"topology has {w}")
 
     if transport is None:
         # time-varying topologies: the sparse/padded-CSR support must cover
@@ -494,50 +836,31 @@ def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
                                    adjacency=support, robust=robust,
                                    shard=shard)
     use_ef = transport.use_ef
-    stochastic = transport.stochastic
-    regen = scenario is not None and scenario.adj_seg is not None
-    dp_update = uses_update_dp(cfg)
-    # masked_geom: the receiver of an aggregate-only secagg sees no
-    # per-peer update, so the geometry/correlation channels are replaced
-    # by the pooled aggregate-minus-own-contribution signal
-    masked_geom = cfg.secagg is not None \
-        and cfg.secagg_mode == "masked_geom"
+    corr = "corr" in resolve_dts_signal(cfg)
 
     if telemetry is not None:
         from repro.telemetry.spec import defta_specs
         telemetry.declare(*defta_specs(w, scenario=scenario is not None,
                                        use_ef=use_ef))
-        tm_specs = telemetry.specs       # snapshot: wrappers may add more
 
-    # ---- stages -----------------------------------------------------------
-
-    def stage_split_keys(c):
-        """reads state.key; writes key (next round), k_sample, k_train,
-        k_noise and — build-time gated — k_wire (stochastic int8 wire)
-        and k_dp (update-DP stage). The split layout is frozen
-        (``split_round_keys``): adding a split changes every downstream
-        draw."""
-        c.update(split_round_keys(c["state"].key, stochastic, dp_update))
+    block = block_stages(
+        task, cfg, train, w, transport, telemetry=telemetry, robust=robust,
+        col_w=col_w,
+        kinds_present=scenario.kinds_present if scenario is not None else (),
+        num_classes=num_classes,
+        malicious=jnp.asarray(malicious) if scenario is None else None,
+        noise_scale=noise_scale)
 
     def stage_scenario_view(c):
-        """reads epoch; writes eff_adj (and alive/fire/att_on with a
-        scenario): the round's effective topology = (per-segment or static)
-        adjacency ∧ link_ok ∧ alive on both endpoints. With
-        ``cfg.max_staleness > 0`` (build-time gated: the default 0 traces
-        no extra ops) edges from peers whose epoch counter lags the
-        receiver's by more than S rounds are additionally dropped — a
-        straggler's S-rounds-old model is excluded from the merge instead
-        of silently mixed (async ticks and straggler scenarios open
-        exactly these gaps)."""
-        if scenario is not None:
-            view = epoch_view(scenario, c["epoch"])
-            c["alive"], c["fire"], c["att_on"] = \
-                view["alive"], view["fire"], view["attack_on"]
-            base = view["adj"] if regen else adj_j
-            c["eff_adj"] = base & view["link_ok"] \
-                & c["alive"][None, :] & c["alive"][:, None]
-        else:
-            c["eff_adj"] = adj_j
+        """reads epoch; writes eff_adj (and alive/fire/att_on/att_kind/
+        att_scale with a scenario): the round's effective topology
+        (``scenario_view``). With ``cfg.max_staleness > 0`` (build-time gated: the
+        default 0 traces no extra ops) edges from peers whose epoch
+        counter lags the receiver's by more than S rounds are additionally
+        dropped — a straggler's S-rounds-old model is excluded from the
+        merge instead of silently mixed (async ticks and straggler
+        scenarios open exactly these gaps)."""
+        scenario_view(c, scenario, adj_j, c["epoch"])
         if max_staleness:
             ep = c["state"].epoch
             fresh = (ep[:, None] - ep[None, :]) <= max_staleness
@@ -548,222 +871,6 @@ def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
             if scenario is not None:
                 telemetry.emit(c, "alive", c["alive"])
                 telemetry.emit(c, "fire", c["fire"])
-
-    def stage_peer_sample(c):
-        """reads eff_adj, state.conf, k_sample; writes theta [W,W] (DTS
-        sampling weights, observed by theta-aware attacks and reused as
-        the geometric reference weights) and sampled [W,W] (Gumbel top-k,
-        ≤ num_sampled per row)."""
-        if cfg.use_dts:
-            theta = dts_mod.sample_weights(c["state"].conf, c["eff_adj"],
-                                           cfg.crelu_slope)        # [W,W]
-        else:
-            theta = c["eff_adj"] / jnp.maximum(
-                c["eff_adj"].sum(1, keepdims=True), 1)
-        c["theta"] = theta
-        skeys = jax.random.split(c["k_sample"], w)
-        c["sampled"] = jax.vmap(
-            lambda k, t: dts_mod.sample_peers(k, t, cfg.num_sampled)
-        )(skeys, theta)                                            # [W,W]
-        if telemetry is not None:
-            telemetry.emit(c, "theta_in", theta.mean(axis=0))
-
-    def stage_transport(c):
-        """reads sampled, eff_adj, state.params, state.wire_err, k_wire;
-        writes P (mixing matrix), agg (the mixed models) and wire_err
-        (advanced EF21 residuals). This is the pluggable stage: in_jit
-        mix_pytree backends, the cross-pod ppermute ring, or a robust
-        rule (trimmed_mean/median/krum) replacing the weighted mix."""
-        state = c["state"]
-        mask = (c["sampled"] & c["eff_adj"]) | jnp.eye(w, dtype=bool)
-        if telemetry is not None:
-            from repro.telemetry.spec import stacked_payload_bytes
-            live = (c["sampled"] & c["eff_adj"]
-                    & ~jnp.eye(w, dtype=bool)).sum()
-            telemetry.emit(c, "edges", live)
-            telemetry.emit(c, "wire_bytes", live.astype(jnp.float32) *
-                           stacked_payload_bytes(state.params,
-                                                 transport.wire))
-        if robust:
-            # classical Byzantine-robust baselines: unweighted rule over
-            # the sampled set; P degrades to the uniform bookkeeping
-            # weights the DTS confidence update needs
-            c["agg"] = robust_mix(cfg.aggregation, mask, state.params,
-                                  trim=cfg.robust_trim)
-            c["P"] = mask / mask.sum(axis=1, keepdims=True)
-            c["wire_err"] = state.wire_err
-            return
-        if scenario is not None:
-            # per-epoch outdegree renormalization under the dynamic
-            # adjacency (churn/link failures change |D_j|/d_j)
-            P = dynamic_mixing_matrix(c["sampled"], c["eff_adj"], sizes_j,
-                                      cfg.aggregation)
-        else:
-            P = mask * col_w[None, :]
-            P = P / P.sum(axis=1, keepdims=True)
-        c["P"] = P
-        round_ = 0 if c["epoch"] is None else c["epoch"]
-        if use_ef:
-            if state.wire_err is None:
-                raise ValueError(
-                    "cfg enables gossip error feedback on a lossy wire "
-                    "but the state carries no residual buffers — build "
-                    "it with init_state(..., wire_error=True)")
-            c["agg"], c["wire_err"] = transport.mix(
-                P, state.params, residual=state.wire_err, key=c["k_wire"],
-                round_=round_)
-            if telemetry is not None:
-                telemetry.emit(c, "ef_norm", jnp.linalg.norm(
-                    dts_mod.flatten_stacked(c["wire_err"]), axis=1))
-        else:
-            c["agg"] = transport.mix(P, state.params, key=c["k_wire"],
-                                     round_=round_)
-            c["wire_err"] = state.wire_err
-
-    def stage_damage_check(c):
-        """reads agg, state.{backup,best_loss}, data; writes y_data
-        (label-flip poisoned labels where active), loss_agg (each worker's
-        self-evaluation of the aggregate), damaged [W] and start (the
-        params local training departs from — the backup on damaged
-        rounds: the §3.3 time machine)."""
-        state, data = c["state"], c["data"]
-        y_data = data["y"]
-        if scenario is not None and "label_flip" in scenario.kinds_present:
-            # data poisoning: label-flippers train (and self-evaluate) on
-            # y -> C-1-y; their protocol behaviour stays honest
-            lf = (scenario.attack_kind == ATTACK_CODE["label_flip"]) \
-                & c["att_on"]
-            y_data = attacks_mod.flip_labels(y_data, lf, num_classes)
-        c["y_data"] = y_data
-        c["loss_agg"] = jax.vmap(task.loss)(c["agg"], data["x"], y_data,
-                                            data["mask"])
-        if cfg.time_machine:
-            c["damaged"] = dts_mod.is_damaged(c["loss_agg"],
-                                              state.best_loss)
-            c["start"] = tree_select(c["damaged"], state.backup, c["agg"])
-        else:
-            c["damaged"] = jnp.zeros_like(c["loss_agg"], bool)
-            c["start"] = c["agg"]
-        if telemetry is not None:
-            telemetry.emit(c, "loss_agg", c["loss_agg"])
-            telemetry.emit(c, "damaged", c["damaged"])
-
-    def stage_local_train(c):
-        """reads start, y_data, data, k_train; writes trained (post-SGD
-        stacked params) and train_loss — ``local_epochs`` minibatch epochs
-        per worker, vmapped over the worker axis."""
-        data = c["data"]
-        tkeys = jax.random.split(c["k_train"], w)
-        c["trained"], c["train_loss"] = jax.vmap(
-            lambda k, p, x, y, m: ltrain(k, p, x, y, m)
-        )(tkeys, c["start"], data["x"], c["y_data"], data["mask"])
-        if telemetry is not None:
-            telemetry.emit(c, "train_loss", c["train_loss"])
-
-    def stage_dp_noise(c):
-        """reads trained, start, k_dp; writes trained — per-round
-        update-DP (``apply_update_dp``): every worker clips its local-
-        update delta and noises it BEFORE it becomes next round's send,
-        so both peers and the trust channels only ever observe the
-        privatized update. Build-time gated on ``uses_update_dp(cfg)``
-        (the default σ=0 compiles this stage away entirely)."""
-        c["trained"] = apply_update_dp(cfg, c["k_dp"], c["start"],
-                                       c["trained"])
-
-    def stage_attack_inject(c):
-        """reads trained, agg, att_on, theta, k_noise; writes trained
-        (attacker slots replaced by their poisoned sends — what peers
-        consume NEXT round). theta feeds the adaptive theta_aware gate."""
-        if scenario is not None:
-            c["trained"] = attacks_mod.poison_sends(
-                c["k_noise"], scenario.kinds_present, scenario.attack_kind,
-                scenario.attack_scale, c["att_on"], c["agg"], c["trained"],
-                theta=c["theta"] if cfg.use_dts else None)
-        else:
-            # legacy path: the paper's aggregate+noise on ``malicious``
-            poisoned = attacks_mod.noise(
-                c["k_noise"], c["agg"], c["trained"],
-                jnp.full((w,), noise_scale, jnp.float32))
-            c["trained"] = tree_select(malicious_j, poisoned, c["trained"])
-
-    def stage_trust_update(c):
-        """reads loss_agg, damaged, sampled, P, theta, state.{conf,
-        best_loss, last_loss} (+ trained, start, eff_adj, fire on the
-        geometric/correlation path, + state.sketch on "corr"/"all");
-        writes conf, backup, best_loss, last_loss (+ sketch: the rotated
-        ring buffer with this round's sign-sketch appended). The
-        confidence update is ``c ← c − m ∘ p · signal`` where signal is
-        the loss delta (dts_signal="loss", Algorithm 3 line 12,
-        bit-exact), the centered update-geometry scores ("geom"), the
-        cross-round collusion-correlation scores ("corr"), or their
-        fusions ("both"/"all") — geometry and the sketches both observe
-        each peer's LOCAL-UPDATE delta ``trained − start`` (the step it
-        applied on top of its adopted aggregate; post attack injection,
-        so the poison is exactly what gets scored) at per-(receiver,
-        peer) resolution."""
-        state = c["state"]
-        loss_trust = jnp.where(c["damaged"], dts_mod.DAMAGE_PENALTY,
-                               c["loss_agg"] - state.last_loss)
-        c["sketch"] = state.sketch
-        if channels and masked_geom:
-            # aggregate-only visibility: the receiver never sees a
-            # per-peer delta, so geometry/correlation degrade to the
-            # pooled aggregate-minus-own-contribution signal, broadcast
-            # uniformly over the receiver's sampled row (it cannot tell
-            # WHICH peer moved the pool) — the measured DTS-vs-secagg
-            # tension the bench records
-            deltas = dts_mod.flatten_stacked(c["trained"]) \
-                - dts_mod.flatten_stacked(c["start"])
-            gmask = c["eff_adj"] & c["fire"][None, :] \
-                if scenario is not None else c["eff_adj"]
-            mg = dts_mod.masked_geom_trust(deltas, c["P"], gmask)
-            c["conf"] = state.conf - c["sampled"] * c["P"] \
-                * (loss_trust + cfg.dts_geom_weight * mg)[:, None]
-        elif channels:
-            # non-firing peers (stragglers) are excluded: fire_merge
-            # discards their this-round delta, so peers never consume it
-            # — scoring it would drift trust on phantom updates
-            deltas = dts_mod.flatten_stacked(c["trained"]) \
-                - dts_mod.flatten_stacked(c["start"])
-            gmask = c["eff_adj"] & c["fire"][None, :] \
-                if scenario is not None else c["eff_adj"]
-            if corr:
-                if state.sketch is None:
-                    raise ValueError(
-                        f"dts_signal={cfg.dts_signal!r} needs the sketch "
-                        f"ring buffer — build the state with "
-                        f"init_state(..., sketch=sketch_shape(cfg))")
-                c["sketch"] = dts_mod.update_sketch(state.sketch, deltas,
-                                                    seed=cfg.seed)
-            c["conf"] = dts_mod.geom_confidence_update(
-                cfg.dts_signal, cfg.dts_geom_weight, state.conf,
-                c["sampled"], c["P"], loss_trust, c["damaged"], deltas,
-                gmask, c["theta"], sketch=c["sketch"],
-                lam_corr=cfg.dts_corr_weight)
-        else:
-            c["conf"] = state.conf - c["sampled"] * c["P"] \
-                * loss_trust[:, None]
-        if telemetry is not None:
-            telemetry.emit(c, "loss_trust", loss_trust)
-            telemetry.emit(c, "conf_in", c["conf"].mean(axis=0))
-            # the scored observable: ‖trained − start‖ per worker (on the
-            # channels path XLA CSEs this with the deltas above)
-            telemetry.emit(c, "update_norm", jnp.linalg.norm(
-                dts_mod.flatten_stacked(c["trained"])
-                - dts_mod.flatten_stacked(c["start"]), axis=1))
-
-        improved = (c["loss_agg"] < state.best_loss) & ~c["damaged"]
-        # the time machine's compensation step RATCHETS: a damaged round
-        # starts from the backup, so its trained result is train(backup) —
-        # clean by induction — and becomes the new backup. Without this a
-        # worker whose whole peer set is malicious (66%-regime reality)
-        # re-trains the same frozen backup forever and never progresses.
-        c["backup"] = tree_select(improved | c["damaged"], c["trained"],
-                                  state.backup)
-        c["best_loss"] = jnp.where(improved, c["loss_agg"],
-                                   state.best_loss)
-        c["last_loss"] = jnp.where(c["damaged"], state.last_loss,
-                                   c["loss_agg"])
 
     def stage_finalize(c):
         """reads trained, backup, conf, best_loss, last_loss, key,
@@ -798,31 +905,11 @@ def build_defta_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
             key=c["key"], epoch=state.epoch + fire.astype(jnp.int32),
             wire_err=wire_err, sketch=sketch)
 
-    stages = (
-        ("split_keys", stage_split_keys),
-        ("scenario_view", stage_scenario_view),
-        ("peer_sample", stage_peer_sample),
-        ("transport", stage_transport),
-        ("damage_check", stage_damage_check),
-        ("local_train", stage_local_train),
-    ) + ((("dp_noise", stage_dp_noise),) if dp_update else ()) + (
-        ("attack_inject", stage_attack_inject),
-        ("trust_update", stage_trust_update),
-        ("finalize", stage_finalize) if scenario is None
-        else ("fire_merge", stage_fire_merge),
-    )
-
-    def round(state: DeFTAState, data, epoch=None):
-        c = {"state": state, "data": data, "epoch": epoch}
-        run_pipeline(stages, c)
-        nxt = constrain_worker_rows(c["next"], shard, w)
-        if telemetry is None:
-            return nxt
-        return nxt, telemetry.collect(c, tm_specs)
-
-    round.stages = stages
-    round.telemetry = telemetry
-    return round
+    stages = block[:1] + (("scenario_view", stage_scenario_view),) \
+        + block[1:] + (("finalize", stage_finalize) if scenario is None
+                       else ("fire_merge", stage_fire_merge),)
+    return round_program(stages, rows=w, telemetry=telemetry, shard=shard,
+                         sizes=sizes_j)
 
 
 def build_fedavg_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
@@ -848,7 +935,6 @@ def build_fedavg_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
     if telemetry is not None:
         from repro.telemetry.spec import fedavg_specs
         telemetry.declare(*fedavg_specs(w))
-        tm_specs = telemetry.specs
 
     def stage_split_keys(c):
         """reads state.key; writes key, k_sel, k_train, k_noise."""
@@ -937,19 +1023,9 @@ def build_fedavg_round(task: Task, cfg: DeFTAConfig, train: TrainConfig,
         ("star_aggregate", stage_star_aggregate),
         ("server_update", stage_server_update),
     )
-
-    def round(state, data, epoch=None):
-        # FedAvg's round is epoch-invariant; the traced index only feeds
-        # the telemetry round stamp (dead when telemetry is None)
-        c = {"state": state, "data": data, "epoch": epoch}
-        run_pipeline(stages, c)
-        if telemetry is None:
-            return c["next"]
-        return c["next"], telemetry.collect(c, tm_specs)
-
-    round.stages = stages
-    round.telemetry = telemetry
-    return round
+    # FedAvg's round is epoch-invariant; the traced index only feeds the
+    # telemetry round stamp (dead when telemetry is None)
+    return round_program(stages, rows=w, telemetry=telemetry)
 
 
 # ---------------------------------------------------------------------------
@@ -1301,12 +1377,15 @@ def init_pod_state(key, npods: int, params=None, *,
 
 def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
                     transport: Transport, adj: np.ndarray,
-                    scenario=None, num_appended: int = 0, self_eval=None):
-    """The multi-pod gossip round as the SAME stage pipeline over the pod
-    axis: scenario_view -> peer_sample (DTS) -> transport (the full wire
-    stack, ppermute or in_jit) -> [damage_check] -> attack_inject ->
-    trust_update. Local training happens between gossip rounds in
-    ``build_fl_train_step``.
+                    scenario=None, self_eval=None):
+    """The multi-pod gossip round over the pod axis: split_keys ->
+    scenario_view -> peer_sample -> transport -> [damage_check] ->
+    attack_inject -> trust_update -> finalize. Its peer sample, mixing and
+    confidence update are the code the dense and cross-device rounds run
+    (``peer_sample``, ``mix``, ``confidence_update``); its key split,
+    time machine, attack injection and finalize are its own. Without DTS
+    a pod listens to every live peer. Local training happens between
+    gossip rounds in ``build_fl_train_step``.
 
     ``self_eval(stacked_params) -> [npods] losses`` is the pod TIME
     MACHINE's held-out self-evaluation: with ``cfg.time_machine`` it is
@@ -1324,21 +1403,15 @@ def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
     geometric/correlation signals). ``start_params`` — the stacked params
     the pods DEPARTED from this round (last round's adopted
     ``new_params``) — makes the geometry/correlation observables the true
-    local-train deltas ``sent − start``, matching the simulation engines
-    exactly (the launcher threads it); when omitted the signals fall back
-    to the round displacement ``out − params``, the legacy pod
-    approximation. The scenario epoch axis is the GOSSIP ROUND index
-    (pstate.round).
-
-    ``num_appended`` attackers from the scenario occupy the LAST pod slots
-    (paper §4.3: attackers newly joined) — the caller sizes the mesh so
-    vanilla + appended == npods.
+    local-train deltas ``sent − start``, as in the simulation engines
+    (the launcher threads it); when omitted the signals fall back to the
+    round displacement ``out − params``. The scenario epoch axis is the
+    GOSSIP ROUND index (pstate.round).
     """
     from repro.scenarios import attacks as attacks_mod
-    from repro.scenarios.compile import ATTACK_CODE, epoch_view
-    from repro.scenarios.robust_agg import ROBUST_RULES, robust_mix
+    from repro.scenarios.compile import ATTACK_CODE
+    from repro.scenarios.robust_agg import ROBUST_RULES
 
-    del num_appended                      # slots are already in npods
     w = npods
     adj_j = jnp.asarray(adj)
     sizes_j = jnp.asarray(np.asarray(sizes, np.float32))
@@ -1349,7 +1422,6 @@ def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
     if scenario is not None and scenario.num_workers != w:
         raise ValueError(f"scenario compiled for W={scenario.num_workers} "
                          f"pods, mesh has {w}")
-    regen = scenario is not None and scenario.adj_seg is not None
     use_ef = transport.use_ef
     channels = resolve_dts_signal(cfg)
     corr = "corr" in channels
@@ -1360,9 +1432,8 @@ def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
             "— use the simulation/cross-device engines to measure the "
             "aggregate-only trust degradation")
     # the pod time machine needs BOTH the flag and a held-out evaluator;
-    # without self_eval the selection quietly stays TM-less (the
-    # pre-existing pod contract — sim configs default time_machine=True
-    # and are reused here)
+    # without self_eval the selection quietly stays TM-less (sim configs
+    # default time_machine=True and are reused here)
     time_machine = cfg.time_machine and self_eval is not None
 
     def stage_split_keys(c):
@@ -1377,59 +1448,24 @@ def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
             c["k_wire"] = None
 
     def stage_scenario_view(c):
-        """reads pstate.round; writes eff_adj (+ alive/fire/att_on with a
-        scenario) — the gossip-round axis is the scenario's epoch axis."""
-        if scenario is not None:
-            view = epoch_view(scenario, c["pstate"].round)
-            c["alive"], c["fire"], c["att_on"] = \
-                view["alive"], view["fire"], view["attack_on"]
-            base = view["adj"] if regen else adj_j
-            c["eff_adj"] = base & view["link_ok"] \
-                & c["alive"][None, :] & c["alive"][:, None]
-        else:
-            c["eff_adj"] = adj_j
+        """reads pstate.round; writes eff_adj (+ alive/fire/att_on/
+        att_kind/att_scale with a scenario: ``scenario_view``) — the
+        gossip-round axis is the scenario's epoch axis."""
+        scenario_view(c, scenario, adj_j, c["pstate"].round)
 
     def stage_peer_sample(c):
         """reads eff_adj, pstate.conf, k_sample; writes theta and sampled
-        (without DTS every live peer is listened to and theta is the
-        uniform row-normalized adjacency)."""
-        if cfg.use_dts:
-            theta = dts_mod.sample_weights(c["pstate"].conf, c["eff_adj"],
-                                           cfg.crelu_slope)
-            skeys = jax.random.split(c["k_sample"], w)
-            c["sampled"] = jax.vmap(
-                lambda k, t: dts_mod.sample_peers(k, t, cfg.num_sampled)
-            )(skeys, theta)
-        else:
-            theta = c["eff_adj"] / jnp.maximum(
-                c["eff_adj"].sum(1, keepdims=True), 1)
-            c["sampled"] = c["eff_adj"]    # listen to every live peer
-        c["theta"] = theta
+        (``peer_sample``; without DTS every live peer is listened to)."""
+        peer_sample(cfg, c, c["pstate"].conf, listen_all=True)
 
     def stage_transport(c):
-        """reads sampled, eff_adj, params, pstate.wire_err, k_wire; writes
-        P, agg, wire_err — the wire stack (fp32/bf16/int8 + EF21) over the
-        in_jit backends or the cross-pod ppermute ring, or a robust rule."""
+        """reads sampled, eff_adj, sizes, params, pstate.{wire_err,round},
+        k_wire; writes P, agg, wire_err (``mix``) — the wire stack
+        (fp32/bf16/int8 + EF21) over the in_jit backends or the cross-pod
+        ppermute ring, or a robust rule."""
         pstate = c["pstate"]
-        mask = (c["sampled"] & c["eff_adj"]) | jnp.eye(w, dtype=bool)
-        c["mask"] = mask
-        if robust:
-            c["agg"] = robust_mix(cfg.aggregation, mask, c["params"],
-                                  trim=cfg.robust_trim)
-            c["P"] = mask / mask.sum(axis=1, keepdims=True)
-            c["wire_err"] = pstate.wire_err
-            return
-        P = dynamic_mixing_matrix(c["sampled"], c["eff_adj"], sizes_j,
-                                  cfg.aggregation)
-        c["P"] = P
-        if use_ef:
-            c["agg"], c["wire_err"] = transport.mix(
-                P, c["params"], residual=pstate.wire_err, key=c["k_wire"],
-                round_=pstate.round)
-        else:
-            c["agg"] = transport.mix(P, c["params"], key=c["k_wire"],
-                                     round_=pstate.round)
-            c["wire_err"] = pstate.wire_err
+        mix(cfg, transport, c, c["params"], pstate.wire_err, pstate.round,
+            robust=robust)
 
     def stage_damage_check(c):
         """reads agg, pstate.{backup,best_loss}; writes eval_loss (the
@@ -1469,54 +1505,30 @@ def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
         c["out"] = tree_select(c["att_active"], poisoned, c["agg"])
 
     def stage_trust_update(c):
-        """reads losses, sampled, P, theta, out, params, att_active,
-        start_params, pstate.{conf, last_loss} (+ pstate.sketch on
-        "corr"/"all"); writes conf (+ sketch: the rotated ring buffer).
-        The same fused loss/geometry/correlation signal as the simulation
-        engines. The observable: with ``start_params`` it is each pod's
-        TRUE local-train delta — the post-attack send (poison for active
-        attackers, the trained params peers actually consume otherwise)
-        minus the params the pod departed from — exact parity with the
-        sim engines' ``trained − start``; without it, the legacy round
-        displacement ``out − params``."""
+        """reads losses, out, params, att_active, start_params,
+        pstate.{conf, last_loss, sketch} (+ damaged) + what
+        ``confidence_update`` reads; writes conf (+ sketch). The scored
+        delta: with ``start_params`` each pod's TRUE local-train delta —
+        the post-attack send (poison for active attackers, the trained
+        params peers actually consume otherwise) minus the params the pod
+        departed from, as the simulation engines' ``trained − start``;
+        without it the round displacement ``out − params``."""
         pstate = c["pstate"]
         damaged = c.get("damaged")
         if damaged is None:
             damaged = jnp.zeros((w,), bool)
         loss_trust = jnp.where(damaged, dts_mod.DAMAGE_PENALTY,
                                c["losses"] - pstate.last_loss)
-        c["sketch"] = pstate.sketch
-        if channels:
-            # same contract as the sim engines (geom_confidence_update):
-            # score the FULL live neighborhood (centering over only the
-            # ~2 sampled peers degenerates to a pairwise coin flip);
-            # non-firing pods' phantom deltas are excluded like
-            # stragglers
-            if c["start_params"] is not None:
-                sent = tree_select(c["att_active"], c["out"], c["params"])
-                deltas = dts_mod.flatten_stacked(sent) \
-                    - dts_mod.flatten_stacked(c["start_params"])
-            else:
-                deltas = dts_mod.flatten_stacked(c["out"]) \
-                    - dts_mod.flatten_stacked(c["params"])
-            gmask = c["eff_adj"] & c["fire"][None, :] \
-                if scenario is not None else c["eff_adj"]
-            if corr:
-                if pstate.sketch is None:
-                    raise ValueError(
-                        f"dts_signal={cfg.dts_signal!r} needs the sketch "
-                        f"ring buffer — build the pod state with "
-                        f"init_pod_state(..., sketch=sketch_shape(cfg))")
-                c["sketch"] = dts_mod.update_sketch(pstate.sketch, deltas,
-                                                    seed=cfg.seed)
-            c["conf"] = dts_mod.geom_confidence_update(
-                cfg.dts_signal, cfg.dts_geom_weight, pstate.conf,
-                c["sampled"], c["P"], loss_trust, damaged, deltas,
-                gmask, c["theta"], sketch=c["sketch"],
-                lam_corr=cfg.dts_corr_weight)
-        else:
-            c["conf"] = pstate.conf - c["sampled"] * c["P"] \
-                * loss_trust[:, None]
+        deltas = None
+        if channels and c["start_params"] is not None:
+            sent = tree_select(c["att_active"], c["out"], c["params"])
+            deltas = dts_mod.flatten_stacked(sent) \
+                - dts_mod.flatten_stacked(c["start_params"])
+        elif channels:
+            deltas = dts_mod.flatten_stacked(c["out"]) \
+                - dts_mod.flatten_stacked(c["params"])
+        confidence_update(cfg, c, pstate.conf, pstate.sketch, loss_trust,
+                          damaged, deltas)
 
     def stage_finalize(c):
         """reads out, conf, losses, wire_err, sketch (+ fire/damaged/
@@ -1570,7 +1582,7 @@ def build_pod_round(cfg: DeFTAConfig, npods: int, sizes, *,
 
     def gossip_round(pstate: PodState, params, losses, start_params=None):
         c = {"pstate": pstate, "params": params, "losses": losses,
-             "start_params": start_params}
+             "start_params": start_params, "sizes": sizes_j}
         run_pipeline(stages, c)
         return c["next"], c["new_params"]
 
@@ -1612,27 +1624,17 @@ class CrossDeviceState:
 def init_cross_device_state(key, task: Task, enrolled: int, *,
                             wire_error: bool = False,
                             sketch=None) -> CrossDeviceState:
-    """``sketch``: the (R, S) dims from ``sketch_shape(cfg)`` when the
-    correlation channel is on, else None. Stamps start at −1: an empty
-    ring slot can never stamp-match, so fresh users carry zero correlation
-    evidence by construction."""
-    keys = jax.random.split(key, enrolled + 1)
-    params = jax.vmap(task.init)(keys[:enrolled])
+    """``init_state`` over the enrolled population, plus the participation
+    bookkeeping. ``sketch``: the (R, S) dims from ``sketch_shape(cfg)``
+    when the correlation channel is on, else None. Stamps start at −1: an
+    empty ring slot can never stamp-match, so fresh users carry zero
+    correlation evidence by construction."""
+    st = init_state(key, task, enrolled, wire_error=wire_error,
+                    sketch=sketch)
     return CrossDeviceState(
-        params=params,
-        backup=jax.tree.map(jnp.copy, params),
-        conf=jnp.zeros((enrolled, enrolled)),
-        best_loss=jnp.full((enrolled,), jnp.inf),
-        last_loss=jnp.zeros((enrolled,)),
-        key=keys[-1],
-        epoch=jnp.zeros((enrolled,), jnp.int32),
+        **vars(st),
         last_part=jnp.zeros((enrolled,), jnp.int32),
         obs=jnp.zeros((enrolled,), jnp.int32),
-        wire_err=jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        if wire_error else None,
-        sketch=jnp.zeros((enrolled,) + tuple(sketch), jnp.float32)
-        if sketch else None,
         sketch_round=jnp.full((enrolled, sketch[0]), -1, jnp.int32)
         if sketch else None,
     )
@@ -1645,12 +1647,12 @@ def build_cross_device_round(task: Task, cfg: DeFTAConfig,
                              transport: Optional[Transport] = None,
                              telemetry=None, shard=None):
     """The cross-device round program: ``participation`` gathers the
-    round's k-member cohort out of the enrolled population, the dense
-    stages the engine already runs execute on the k-block, and
-    ``scatter_merge`` writes the survivors' state back — one scannable
-    body, so ``drive_epochs`` fuses a whole eval window of gather →
-    superstep → scatter into a single XLA dispatch exactly like the dense
-    path.
+    round's k-member cohort out of the enrolled population into a k-row
+    ``DeFTAState`` block, ``block_stages`` — the dense round's stages —
+    run on it, and ``scatter_merge`` writes the survivors' state back: one
+    scannable body, so ``drive_epochs`` fuses a whole eval window of
+    gather → cohort block → scatter into a single XLA dispatch exactly
+    like the dense path.
 
     ``world`` is a ``repro.scenarios.cross_device.CompiledWorld``: the
     per-round cohort indices, mid-round dropout / straggler-timeout draws,
@@ -1670,6 +1672,10 @@ def build_cross_device_round(task: Task, cfg: DeFTAConfig,
     * vacancy (fewer available users than k): pad slots carry
       ``filled=False``, never fire, and are masked out of everything.
 
+    Attackers attack whenever they participate: ``att_on`` is the
+    participation mask, gathered from the enrolled-population assignment
+    (29% of ENROLLED means ~29% of every cohort in expectation).
+
     Trust stays calibrated under sparse observation: gathered confidence
     rows decay toward the uninformative prior (0) by
     ``cfg.dts_conf_decay ** (rounds since the row's user last fired)`` —
@@ -1687,26 +1693,17 @@ def build_cross_device_round(task: Task, cfg: DeFTAConfig,
     scatter_merge writes back to the owning shard; the round constrains
     its output state so the donated scan carry stays row-sharded.
     """
+    from repro.scenarios.robust_agg import ROBUST_RULES
+
     n = int(world.enrolled)
     k = int(world.sample_k)
-    ltrain = local_train_fn(task, train, cfg.local_epochs,
-                            dp_clip=cfg.dp_clip, dp_sigma=cfg.dp_sigma)
-    channels = resolve_dts_signal(cfg)
-    corr = "corr" in channels
+    corr = "corr" in resolve_dts_signal(cfg)
     decay = float(cfg.dts_conf_decay)
     max_staleness = int(cfg.max_staleness)
-    k_min = int(world.k_min)
     sizes_j = jnp.asarray(np.asarray(sizes, np.float32))
-
-    from repro.scenarios import attacks as attacks_mod
-    from repro.scenarios.compile import ATTACK_CODE
-    from repro.scenarios.robust_agg import ROBUST_RULES
 
     if world.epochs <= 0:
         raise ValueError("cross-device world compiled for 0 rounds")
-    if "label_flip" in world.kinds_present and num_classes <= 0:
-        raise ValueError("label_flip cross-device world needs "
-                         "num_classes > 0")
     if cfg.aggregation in ROBUST_RULES:
         raise ValueError(
             f"robust aggregation ({cfg.aggregation!r}) has no "
@@ -1721,15 +1718,10 @@ def build_cross_device_round(task: Task, cfg: DeFTAConfig,
         transport = make_transport(cfg, backend=gossip_backend,
                                    adjacency=support)
     use_ef = transport.use_ef
-    stochastic = transport.stochastic
-    dp_update = uses_update_dp(cfg)
-    masked_geom = cfg.secagg is not None \
-        and cfg.secagg_mode == "masked_geom"
 
     if telemetry is not None:
         from repro.telemetry.spec import cross_device_specs
         telemetry.declare(*cross_device_specs(k, use_ef=use_ef))
-        tm_specs = telemetry.specs
 
     part_ix = jnp.asarray(world.part_ix)        # [T, k] int32, per-round
     filled_t = jnp.asarray(world.filled)        # [T, k] bool
@@ -1738,47 +1730,53 @@ def build_cross_device_round(task: Task, cfg: DeFTAConfig,
     adj_t = jnp.asarray(world.adj)              # [T, k, k] bool
     att_kind_u = jnp.asarray(world.attack_kind)     # [N] int32
     att_scale_u = jnp.asarray(world.attack_scale)   # [N] float32
-    eye_k = jnp.eye(k, dtype=bool)
 
-    # ---- stages -----------------------------------------------------------
+    block = block_stages(task, cfg, train, k, transport, telemetry=telemetry,
+                         k_min=int(world.k_min),
+                         kinds_present=world.kinds_present,
+                         num_classes=num_classes)
 
     def stage_participation(c):
-        """reads epoch (the global round t), state.*, data; writes ix (the
+        """reads epoch (the global round t), state (the population), data,
+        sizes; writes population (the state it read), state (the cohort's
+        k-row DeFTAState block: params, backup, wire_err, best_loss,
+        last_loss, the decayed [k, k] confidence block, sketch, and the
+        population key), data and sizes (the cohort's shards), ix (the
         cohort), active/fire (dropout ∧ straggler ∧ filled), eff_adj (the
-        survivor-masked cohort topology), the gathered g_* k-blocks of
-        every population buffer (confidence rows decayed by the time since
-        their user last fired), and the gathered data shards / attack
-        assignment. The gather: one x[ix] per buffer — XLA fuses it into
-        the scan body, no extra dispatch."""
-        state, t = c["state"], c["epoch"]
+        survivor-masked cohort topology), att_kind/att_scale/att_on,
+        g_conf_raw/g_conf_rows (the undecayed/decayed [k, N] confidence
+        rows), g_last_part, g_obs (+ g_stamp). The gather: one x[ix] per
+        buffer — XLA fuses it into the scan body, no extra dispatch."""
+        pop, t = c["state"], c["epoch"]
         ix = part_ix[t]
         c["ix"] = ix
         active = filled_t[t] & survive_t[t]
         c["active"] = active
         c["fire"] = active & complete_t[t]
-        c["g_params"] = jax.tree.map(lambda x: x[ix], state.params)
-        c["g_backup"] = jax.tree.map(lambda x: x[ix], state.backup)
-        c["g_wire_err"] = jax.tree.map(lambda x: x[ix], state.wire_err) \
+        params = jax.tree.map(lambda x: x[ix], pop.params)
+        backup = jax.tree.map(lambda x: x[ix], pop.backup)
+        wire_err = jax.tree.map(lambda x: x[ix], pop.wire_err) \
             if use_ef else None
-        c["g_last_part"] = state.last_part[ix]
-        c["g_conf_raw"] = state.conf[ix]                 # [k, N]
+        c["g_last_part"] = pop.last_part[ix]
+        c["g_conf_raw"] = pop.conf[ix]                   # [k, N]
         rows = c["g_conf_raw"]
         if decay < 1.0:
             gap = jnp.maximum(t - c["g_last_part"], 0).astype(jnp.float32)
             rows = rows * jnp.power(jnp.float32(decay), gap)[:, None]
         c["g_conf_rows"] = rows
-        c["conf"] = rows[:, ix]                          # the [k, k] block
-        c["g_best"] = state.best_loss[ix]
-        c["g_last"] = state.last_loss[ix]
-        c["g_obs"] = state.obs[ix]
+        c["population"] = pop
+        c["state"] = DeFTAState(
+            params=params, backup=backup, conf=rows[:, ix],
+            best_loss=pop.best_loss[ix], last_loss=pop.last_loss[ix],
+            key=pop.key, epoch=None, wire_err=wire_err,
+            sketch=pop.sketch[ix] if corr else None)
+        c["g_obs"] = pop.obs[ix]
         if corr:
-            c["g_sketch"] = state.sketch[ix]
-            c["g_stamp"] = state.sketch_round[ix]
+            c["g_stamp"] = pop.sketch_round[ix]
         data = c["data"]
-        c["g_x"] = data["x"][ix]
-        c["g_y"] = data["y"][ix]
-        c["g_mask"] = data["mask"][ix]
-        c["g_sizes"] = sizes_j[ix]
+        c["data"] = {"x": data["x"][ix], "y": data["y"][ix],
+                     "mask": data["mask"][ix]}
+        c["sizes"] = sizes_j[ix]
         c["att_kind"] = att_kind_u[ix]
         c["att_scale"] = att_scale_u[ix]
         c["att_on"] = active & (c["att_kind"] > 0)
@@ -1798,238 +1796,53 @@ def build_cross_device_round(task: Task, cfg: DeFTAConfig,
             telemetry.emit(c, "fire", c["fire"])
             telemetry.emit(c, "scatter_writes", c["fire"].sum())
 
-    def stage_split_keys(c):
-        """reads state.key; writes key, k_sample, k_train, k_noise
-        (+ build-time gated k_wire / k_dp) — the same frozen split
-        layout as the dense round (``split_round_keys``)."""
-        c.update(split_round_keys(c["state"].key, stochastic, dp_update))
-
-    def stage_peer_sample(c):
-        """reads conf (the decayed k-block), eff_adj, k_sample; writes
-        theta and sampled over the cohort."""
-        if cfg.use_dts:
-            theta = dts_mod.sample_weights(c["conf"], c["eff_adj"],
-                                           cfg.crelu_slope)
-        else:
-            theta = c["eff_adj"] / jnp.maximum(
-                c["eff_adj"].sum(1, keepdims=True), 1)
-        c["theta"] = theta
-        skeys = jax.random.split(c["k_sample"], k)
-        c["sampled"] = jax.vmap(
-            lambda kk, th: dts_mod.sample_peers(kk, th, cfg.num_sampled)
-        )(skeys, theta)
-
-    def stage_transport(c):
-        """reads sampled, eff_adj, g_params, g_wire_err; writes P, agg,
-        wire_err. The mixing matrix renormalizes over SURVIVORS (dropped
-        slots left eff_adj in participation) and rows with < k_min
-        surviving sampled peers degrade to the identity self-loop."""
-        P = dynamic_mixing_matrix(c["sampled"], c["eff_adj"], c["g_sizes"],
-                                  cfg.aggregation)
-        if k_min > 1:
-            npeers = (c["sampled"] & c["eff_adj"] & ~eye_k).sum(axis=1)
-            P = jnp.where((npeers >= k_min)[:, None], P,
-                          eye_k.astype(P.dtype))
-        c["P"] = P
-        if telemetry is not None:
-            from repro.telemetry.spec import stacked_payload_bytes
-            live = (c["sampled"] & c["eff_adj"] & ~eye_k).sum()
-            telemetry.emit(c, "edges", live)
-            telemetry.emit(c, "wire_bytes", live.astype(jnp.float32) *
-                           stacked_payload_bytes(c["g_params"],
-                                                 transport.wire))
-        round_ = 0 if c["epoch"] is None else c["epoch"]
-        if use_ef:
-            c["agg"], c["wire_err"] = transport.mix(
-                P, c["g_params"], residual=c["g_wire_err"],
-                key=c["k_wire"], round_=round_)
-            if telemetry is not None:
-                telemetry.emit(c, "ef_norm", jnp.linalg.norm(
-                    dts_mod.flatten_stacked(c["wire_err"]), axis=1))
-        else:
-            c["agg"] = transport.mix(P, c["g_params"], key=c["k_wire"],
-                                     round_=round_)
-            c["wire_err"] = c["g_wire_err"]
-
-    def stage_damage_check(c):
-        """reads agg, g_y, g_x, g_mask, g_best, g_backup, att_kind,
-        att_on; writes y_data, loss_agg, damaged, start — identical to
-        the dense round, on the gathered cohort block."""
-        y = c["g_y"]
-        if "label_flip" in world.kinds_present:
-            lf = (c["att_kind"] == ATTACK_CODE["label_flip"]) & c["att_on"]
-            y = attacks_mod.flip_labels(y, lf, num_classes)
-        c["y_data"] = y
-        c["loss_agg"] = jax.vmap(task.loss)(c["agg"], c["g_x"], y,
-                                            c["g_mask"])
-        if cfg.time_machine:
-            c["damaged"] = dts_mod.is_damaged(c["loss_agg"], c["g_best"])
-            c["start"] = tree_select(c["damaged"], c["g_backup"], c["agg"])
-        else:
-            c["damaged"] = jnp.zeros_like(c["loss_agg"], bool)
-            c["start"] = c["agg"]
-        if telemetry is not None:
-            telemetry.emit(c, "loss_agg", c["loss_agg"])
-
-    def stage_local_train(c):
-        """reads start, g_x, y_data, g_mask, k_train; writes trained,
-        train_loss — the dense stage body vmapped over the k cohort."""
-        tkeys = jax.random.split(c["k_train"], k)
-        c["trained"], c["train_loss"] = jax.vmap(
-            lambda kk, p, x, y, m: ltrain(kk, p, x, y, m)
-        )(tkeys, c["start"], c["g_x"], c["y_data"], c["g_mask"])
-        if telemetry is not None:
-            telemetry.emit(c, "train_loss", c["train_loss"])
-
-    def stage_dp_noise(c):
-        """reads trained, start, k_dp; writes trained — the dense
-        round's per-round update-DP stage on the cohort block (see
-        ``apply_update_dp``; build-time gated on ``uses_update_dp``)."""
-        c["trained"] = apply_update_dp(cfg, c["k_dp"], c["start"],
-                                       c["trained"])
-
-    def stage_attack_inject(c):
-        """reads trained, agg, att_kind, att_scale, att_on, theta,
-        k_noise; writes trained. Attackers attack whenever they
-        participate — attack_on is the participation mask itself,
-        gathered from the enrolled-population assignment (29% of
-        ENROLLED means ~29% of every cohort in expectation)."""
-        if world.kinds_present:
-            c["trained"] = attacks_mod.poison_sends(
-                c["k_noise"], world.kinds_present, c["att_kind"],
-                c["att_scale"], c["att_on"], c["agg"], c["trained"],
-                theta=c["theta"] if cfg.use_dts else None)
-
-    def stage_trust_update(c):
-        """reads conf, sampled, P, theta, eff_adj, fire, loss_agg,
-        damaged, g_last, g_best, g_backup, trained, start (+ g_sketch,
-        g_stamp on the corr channel); writes conf_new, backup,
-        best_loss, last_loss (+ sketch, stamp). The dense trust_update
-        on the cohort block, with the correlation channel swapped for
-        its sparse-observation variant: ring buffers rotate WITH a
-        global-round stamp, correlation is scored over stamp-matched
-        slot pairs only, and pairs with < cfg.dts_min_obs common
-        observations are excluded from both the suspicion and its
-        median+MAD baseline."""
-        loss_trust = jnp.where(c["damaged"], dts_mod.DAMAGE_PENALTY,
-                               c["loss_agg"] - c["g_last"])
-        if channels and masked_geom:
-            # aggregate-only visibility on the cohort block: pooled
-            # signal only, no per-peer geometry, and the sketch ring
-            # never rotates (a receiver cannot sketch deltas it never
-            # saw) — stamps pass through unchanged
-            deltas = dts_mod.flatten_stacked(c["trained"]) \
-                - dts_mod.flatten_stacked(c["start"])
-            gmask = c["eff_adj"] & c["fire"][None, :]
-            mg = dts_mod.masked_geom_trust(deltas, c["P"], gmask)
-            if corr:
-                c["sketch"], c["stamp"] = c["g_sketch"], c["g_stamp"]
-            c["conf_new"] = c["conf"] - c["sampled"] * c["P"] \
-                * (loss_trust + cfg.dts_geom_weight * mg)[:, None]
-        elif channels:
-            deltas = dts_mod.flatten_stacked(c["trained"]) \
-                - dts_mod.flatten_stacked(c["start"])
-            gmask = c["eff_adj"] & c["fire"][None, :]
-            gs = dts_mod.geom_scores(deltas, gmask, weights=c["theta"]) \
-                if "geom" in channels else None
-            cs = None
-            if corr:
-                c["sketch"] = dts_mod.update_sketch(c["g_sketch"], deltas,
-                                                    seed=cfg.seed)
-                c["stamp"] = jnp.concatenate(
-                    [c["g_stamp"][:, 1:],
-                     jnp.full((k, 1), c["epoch"], jnp.int32)], axis=1)
-                cmat, valid = dts_mod.stamped_correlation(
-                    c["sketch"], c["stamp"], min_obs=cfg.dts_min_obs)
-                cs = dts_mod.correlation_suspicion(
-                    cmat, gmask, weights=c["theta"], valid=valid)
-            signal = dts_mod.fused_trust_signal(
-                cfg.dts_signal, loss_trust, gs, c["damaged"],
-                cfg.dts_geom_weight, corr=cs,
-                lam_corr=cfg.dts_corr_weight)
-            c["conf_new"] = c["conf"] - c["sampled"] * c["P"] * signal
-        else:
-            c["conf_new"] = c["conf"] - c["sampled"] * c["P"] \
-                * loss_trust[:, None]
-        if telemetry is not None:
-            telemetry.emit(c, "loss_trust", loss_trust)
-            telemetry.emit(c, "conf_in", c["conf_new"].mean(axis=0))
-            telemetry.emit(c, "update_norm", jnp.linalg.norm(
-                dts_mod.flatten_stacked(c["trained"])
-                - dts_mod.flatten_stacked(c["start"]), axis=1))
-
-        improved = (c["loss_agg"] < c["g_best"]) & ~c["damaged"]
-        c["backup"] = tree_select(improved | c["damaged"], c["trained"],
-                                  c["g_backup"])
-        c["best_loss"] = jnp.where(improved, c["loss_agg"], c["g_best"])
-        c["last_loss"] = jnp.where(c["damaged"], c["g_last"],
-                                   c["loss_agg"])
-
     def stage_scatter_merge(c):
-        """reads fire + every updated cohort buffer; writes next (the
-        population state). Fire-gated: non-firing cohort members and
-        absent users scatter back their ORIGINAL (undecayed) rows, so
-        every carried buffer — trust, EF residuals, sketch history,
-        stamps — is bit-unchanged across rounds a user misses. Cohort
-        indices are distinct within a round, so the row scatters never
-        conflict."""
-        state, t, ix, fire = c["state"], c["epoch"], c["ix"], c["fire"]
+        """reads population, state (the gathered block), ix, fire, epoch,
+        trained, backup, wire_err, conf, best_loss, last_loss, key,
+        g_conf_rows, g_conf_raw, g_last_part, g_obs (+ sketch, stamp,
+        g_stamp); writes next (the population state). Fire-gated:
+        non-firing cohort members and absent users scatter back their
+        ORIGINAL (undecayed) rows, so every carried buffer — trust, EF
+        residuals, sketch history, stamps — is bit-unchanged across rounds
+        a user misses. Cohort indices are distinct within a round, so the
+        row scatters never conflict."""
+        pop, blk = c["population"], c["state"]
+        t, ix, fire = c["epoch"], c["ix"], c["fire"]
 
         def scat_tree(full, new_rows, old_rows):
             sel = tree_select(fire, new_rows, old_rows)
             return jax.tree.map(lambda f, s: f.at[ix].set(s), full, sel)
 
-        params = scat_tree(state.params, c["trained"], c["g_params"])
-        backup = scat_tree(state.backup, c["backup"], c["g_backup"])
-        wire_err = scat_tree(state.wire_err, c["wire_err"],
-                             c["g_wire_err"]) if use_ef else state.wire_err
-        rows_new = c["g_conf_rows"].at[:, ix].set(c["conf_new"])
-        conf = state.conf.at[ix].set(
+        params = scat_tree(pop.params, c["trained"], blk.params)
+        backup = scat_tree(pop.backup, c["backup"], blk.backup)
+        wire_err = scat_tree(pop.wire_err, c["wire_err"],
+                             blk.wire_err) if use_ef else pop.wire_err
+        rows_new = c["g_conf_rows"].at[:, ix].set(c["conf"])
+        conf = pop.conf.at[ix].set(
             jnp.where(fire[:, None], rows_new, c["g_conf_raw"]))
         if corr:
-            sketch = state.sketch.at[ix].set(
-                jnp.where(fire[:, None, None], c["sketch"],
-                          c["g_sketch"]))
-            stamps = state.sketch_round.at[ix].set(
+            sketch = pop.sketch.at[ix].set(
+                jnp.where(fire[:, None, None], c["sketch"], blk.sketch))
+            stamps = pop.sketch_round.at[ix].set(
                 jnp.where(fire[:, None], c["stamp"], c["g_stamp"]))
         else:
-            sketch, stamps = state.sketch, state.sketch_round
+            sketch, stamps = pop.sketch, pop.sketch_round
         c["next"] = CrossDeviceState(
             params=params, backup=backup, conf=conf,
-            best_loss=state.best_loss.at[ix].set(
-                jnp.where(fire, c["best_loss"], c["g_best"])),
-            last_loss=state.last_loss.at[ix].set(
-                jnp.where(fire, c["last_loss"], c["g_last"])),
+            best_loss=pop.best_loss.at[ix].set(
+                jnp.where(fire, c["best_loss"], blk.best_loss)),
+            last_loss=pop.last_loss.at[ix].set(
+                jnp.where(fire, c["last_loss"], blk.last_loss)),
             key=c["key"],
-            epoch=state.epoch.at[ix].add(fire.astype(jnp.int32)),
-            last_part=state.last_part.at[ix].set(
+            epoch=pop.epoch.at[ix].add(fire.astype(jnp.int32)),
+            last_part=pop.last_part.at[ix].set(
                 jnp.where(fire, t, c["g_last_part"])),
-            obs=state.obs.at[ix].set(
+            obs=pop.obs.at[ix].set(
                 jnp.where(fire, c["g_obs"] + 1, c["g_obs"])),
             wire_err=wire_err, sketch=sketch, sketch_round=stamps)
 
-    stages = (
-        ("participation", stage_participation),
-        ("split_keys", stage_split_keys),
-        ("peer_sample", stage_peer_sample),
-        ("transport", stage_transport),
-        ("damage_check", stage_damage_check),
-        ("local_train", stage_local_train),
-    ) + ((("dp_noise", stage_dp_noise),) if dp_update else ()) + (
-        ("attack_inject", stage_attack_inject),
-        ("trust_update", stage_trust_update),
-        ("scatter_merge", stage_scatter_merge),
-    )
-
-    def round(state: CrossDeviceState, data, epoch=None):
-        c = {"state": state, "data": data, "epoch": epoch}
-        run_pipeline(stages, c)
-        nxt = constrain_worker_rows(c["next"], shard, n)
-        if telemetry is None:
-            return nxt
-        return nxt, telemetry.collect(c, tm_specs)
-
-    round.stages = stages
-    round.cohort = (n, k)
-    round.telemetry = telemetry
-    return round
+    stages = (("participation", stage_participation),) + block \
+        + (("scatter_merge", stage_scatter_merge),)
+    rnd = round_program(stages, rows=n, telemetry=telemetry, shard=shard)
+    rnd.cohort = (n, k)
+    return rnd

@@ -226,6 +226,20 @@ class TestCrossDeviceRoundProgram:
             assert f"`{name}`" in doc, \
                 f"docs/ARCHITECTURE.md does not document `{name}`"
 
+    def test_cohort_block_runs_the_dense_round_stages(self):
+        """Between ``participation`` and ``scatter_merge`` the cross-device
+        round runs the dense round's own stage code, not copies of it."""
+        task, cfg, world, rnd, _, _ = _build_cd()
+        w = 4
+        adj = np.eye(w, k=1, dtype=bool) | np.eye(w, k=-1, dtype=bool)
+        dense = build_defta_round(task, cfg, TrainConfig(), adj,
+                                  np.full(w, 8), np.zeros(w, bool))
+        code = {name: fn.__code__ for name, fn in dense.stages}
+        block = rnd.stages[1:-1]
+        assert [name for name, _ in block] == list(CD_STAGES[1:-1])
+        for name, fn in block:
+            assert fn.__code__ is code[name], name
+
     def test_k_min_shortfall_degrades_to_identity_mixing(self):
         """With k_min = k and a 1-out cohort graph no row can reach k_min
         surviving sampled peers — every mixing row must be the identity
@@ -429,3 +443,19 @@ class TestSparseObservationTrust:
         s = np.asarray(s)
         honest_max = s[0, :3].max()
         assert s[0, 3] > honest_max and s[0, 4] > honest_max
+
+
+# ---------------------------------------------------------------------------
+# Golden parity: the cross-device round, bit for bit
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["cross_device_loss", "cross_device_all",
+                                  "cross_device_masked"])
+def test_golden_cross_device(name, assert_golden):
+    """The whole population state after a cross-device run (params,
+    backups, the [N, N] confidences, losses, counters, EF residuals,
+    sketches and their stamps) equals the state captured on the engine
+    whose cross-device round still carried its own copies of the dense
+    stages."""
+    from capture_engine_goldens import ROUND_GOLDENS
+    assert_golden(name, ROUND_GOLDENS[name]())

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from capture_engine_goldens import defta_state_digest, setup, tree_digest
+from capture_engine_goldens import (ROUND_GOLDENS, defta_state_digest, setup,
+                                    tree_digest)
 from repro.config import DeFTAConfig, TrainConfig
 from repro.core.async_defta import run_async_defta
 from repro.core.defta import run_defta
@@ -95,6 +96,18 @@ def test_golden_fedavg_variants(env, assert_golden):
     st = run_fedavg(jax.random.PRNGKey(0), task, cfg, train, data,
                     epochs=4, sample_workers=2)
     assert_golden("fedavg_sampled", {"server": tree_digest(st.server)})
+
+
+@pytest.mark.parametrize("name", ["pod_round_loss", "pod_round_all",
+                                  "pod_round_nodts", "defta_all_storm",
+                                  "defta_masked_geom"])
+def test_golden_round_stages(name, assert_golden):
+    """The pod round and the dense round's trust channels reproduce the
+    state captured on the engine before the three round builders shared
+    their peer-sample, mixing and confidence-update code: key, counters,
+    confidences, backups, EF residuals, sketches and params, bit for
+    bit."""
+    assert_golden(name, ROUND_GOLDENS[name]())
 
 
 # ---------------------------------------------------------------------------

@@ -139,11 +139,12 @@ def test_qwen3_two_pod_adafactor_train_step_fits_v5e(one_chip):
     assert used < V5E_HBM_BYTES, used / 1e9
 
 
-def test_qwen3_two_pod_int8_gossip_round_fits_v5e(one_chip):
-    """The same run's gossip round (int8 wire, EF21 on, pod DTS): its
-    arguments (stacked params plus pod state with the f32 EF residuals)
-    and temporaries, plus the optimizer state that stays resident between
-    train steps, stay under the chip's 16 GB."""
+@pytest.fixture(scope="module")
+def qwen3_gossip_round(one_chip):
+    """The qwen3.pods2.gossip4 cell's gossip round (int8 wire, EF21 on,
+    pod DTS on the loss delta, 2 full-width qwen3-0.6b pods on one chip)
+    lowered and compiled for a described v5e: (lowered, compiled, the
+    optimizer-state bytes that stay resident between train steps)."""
     from repro.config import DeFTAConfig
     from repro.core.engine import init_pod_state
     from repro.core.topology import make_topology
@@ -164,17 +165,34 @@ def test_qwen3_two_pod_int8_gossip_round_fits_v5e(one_chip):
         jax.eval_shape(lambda p: init_pod_state(
             jax.random.PRNGKey(0), PODS, p, wire_error=True), stacked))
     assert _nbytes(pstate.wire_err) == _ef_residual_bytes(params)
-    compiled = jax.jit(rnd, donate_argnums=(0, 1)).lower(
-        pstate, stacked, _sds((PODS,), jnp.float32, one_chip),
-        None).compile()
-    mem = compiled.memory_analysis()
+    lowered = jax.jit(rnd, donate_argnums=(0, 1)).lower(
+        pstate, stacked, _sds((PODS,), jnp.float32, one_chip), None)
     opt = make_optimizer("adafactor", 1e-3)
     resident = _nbytes(stack(jax.eval_shape(opt.init, params)))
+    return lowered, lowered.compile(), resident
+
+
+def test_qwen3_two_pod_int8_gossip_round_fits_v5e(qwen3_gossip_round):
+    """The same run's gossip round (int8 wire, EF21 on, pod DTS): its
+    arguments (stacked params plus pod state with the f32 EF residuals)
+    and temporaries, plus the optimizer state that stays resident between
+    train steps, stay under the chip's 16 GB."""
+    _, compiled, resident = qwen3_gossip_round
+    mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + resident
     assert used < V5E_HBM_BYTES, used / 1e9
 
 
 _INSTR = r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = "
+# the gossip round of qwen3.pods2.gossip4 and the round of cnn21.paper,
+# counted on the tree before the three round builders shared their stages
+QWEN3_GOSSIP_LOWERED = 1355
+QWEN3_GOSSIP_TEMP_BYTES = 2_490_351_104
+CNN21_ROUND_LOWERED = 3927
+
+
+def _count(text):
+    return len(re.findall(_INSTR, text, re.M))
 
 
 def test_qwen3_pod_step_compiles_to_the_same_program(one_chip):
@@ -183,8 +201,6 @@ def test_qwen3_pod_step_compiles_to_the_same_program(one_chip):
     with as many temporary bytes, as before the latent-attention and
     sigmoid-router changes to the shared model code (counted on the tree
     before them: 2,380 lowered, 4,437 compiled, 8,101,410,816 bytes)."""
-    import re
-
     from repro.launch.steps import build_fl_train_step
     from repro.optim import make_optimizer
 
@@ -195,11 +211,68 @@ def test_qwen3_pod_step_compiles_to_the_same_program(one_chip):
                       donate_argnums=(0, 1)).lower(
         stack(params), stack(jax.eval_shape(opt.init, params)),
         _sds((), jnp.int32, one_chip), {"tokens": toks, "labels": toks})
-    count = lambda text: len(re.findall(_INSTR, text, re.M))
-    assert count(lowered.as_text(dialect="hlo")) == 2380
+    assert _count(lowered.as_text(dialect="hlo")) == 2380
     compiled = lowered.compile()
-    assert count(compiled.as_text()) == 4437
+    assert _count(compiled.as_text()) == 4437
     assert compiled.memory_analysis().temp_size_in_bytes == 8_101_410_816
+
+
+def test_qwen3_gossip_round_compiles_to_the_same_program(
+        qwen3_gossip_round):
+    """The qwen3 cell's gossip round lowers to as many HLO instructions,
+    and compiles with as many temporary bytes, as before its peer sample,
+    mixing and confidence update became the code the dense and
+    cross-device rounds run (counted on the tree before that change)."""
+    lowered, compiled, _ = qwen3_gossip_round
+    assert _count(lowered.as_text(dialect="hlo")) == QWEN3_GOSSIP_LOWERED
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        == QWEN3_GOSSIP_TEMP_BYTES
+
+
+def test_cnn21_round_lowers_to_the_same_program(one_chip, monkeypatch):
+    """The cnn21.paper cell's round (21 CNN workers of 300 padded 28x28
+    images, the paper_noise attacker, random 4-out topology, 2 sampled
+    peers, 10 local epochs, DTS, the sparse int8 + EF21 wire) lowered for
+    a described v5e, its gossip kernels through Mosaic as on the chip:
+    as many HLO instructions as before the dense, pod and cross-device
+    rounds shared their stages (counted on the tree before that change)."""
+    from importlib import import_module
+
+    from repro.config import DeFTAConfig, TrainConfig
+    from repro.core.defta import build_round_fn, resolve_scenario
+    from repro.core.engine import init_state, sketch_shape
+    from repro.core.gossip import uses_error_feedback
+    from repro.core.tasks import cnn_task
+    from repro.core.topology import make_topology
+
+    # the package re-exports the kernel under the module's name
+    sparse_mod = import_module("repro.kernels.gossip_mix_sparse")
+    monkeypatch.setattr(sparse_mod, "resolve_interpret",
+                        lambda interpret=None: False)
+    task = cnn_task(28, 1, 10, width=16)
+    cfg = DeFTAConfig(num_workers=20, avg_peers=4, num_sampled=2,
+                      topology="random_kout", local_epochs=10,
+                      gossip_dtype="int8", gossip_error_feedback=True,
+                      use_dts=True, seed=0)
+    train = TrainConfig(learning_rate=0.05, batch_size=32)
+    scenario = resolve_scenario("paper_noise", cfg, 4000)
+    w, n = scenario.num_workers, 300
+    adj = make_topology(cfg.topology, w, cfg.avg_peers, cfg.seed)
+    rnd = build_round_fn(task, cfg, train, adj, np.full(w, 200),
+                         scenario.malicious, gossip_backend="sparse",
+                         scenario=scenario, num_classes=10)
+    state = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda k: init_state(
+            k, task, w, wire_error=uses_error_feedback(cfg),
+            sketch=sketch_shape(cfg)), jax.random.PRNGKey(0)))
+    data = {"x": _sds((w, n, 28 * 28), jnp.float32, one_chip),
+            "y": _sds((w, n), jnp.int32, one_chip),
+            "mask": _sds((w, n), jnp.float32, one_chip)}
+    text = jax.jit(rnd).lower(state, data, _sds((), jnp.int32, one_chip)
+                              ).as_text(dialect="hlo")
+    assert "tpu_custom_call" in text
+    assert _count(text) == CNN21_ROUND_LOWERED
 
 
 @pytest.fixture(scope="module")
